@@ -115,4 +115,6 @@ def main(quick: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(quick="--quick" in sys.argv)

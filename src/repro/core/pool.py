@@ -595,12 +595,18 @@ class TokenPool:
         Single-request admission is inherently scalar, so this uses the
         scalar oracle directly; the accounting tick computes the same
         weights for ALL rows on the vectorized control plane (pinned
-        equal by ``tests/test_control_plane.py``)."""
+        equal by ``tests/test_control_plane.py``).
+
+        The SLO target is read from the resident f32 ``slo_ms`` column,
+        like burst and debt — the same value :meth:`admission_threshold`
+        and the kernels read.  The spec's float64 target may differ from
+        it in the last bits, and an entitlement that sets the threshold
+        must tie with itself exactly under the strict check 5."""
         espec = self.entitlements[name]
         st = self.status[name]
         return prio.priority_weight(
             espec.qos.service_class,
-            espec.qos.slo_target_ms,
+            float(self.store.col["slo_ms"][self.store.slot_of[name]]),
             self.pool_avg_slo(),
             st.burst,
             st.debt,
@@ -1074,10 +1080,11 @@ class TokenPool:
     def _kernel_inputs(self) -> tuple:
         """f32 device views of the measurement columns (full width)."""
         c = self.store.col
-        return (jnp.asarray(c["measured_tps"].astype(np.float32)),
-                jnp.asarray(c["kv_in_use"].astype(np.float32)),
-                jnp.asarray(c["resident"].astype(np.float32)),
-                jnp.asarray(c["demand_tps"].astype(np.float32)))
+        put = self.store.put_rows
+        return (put(c["measured_tps"].astype(np.float32)),
+                put(c["kv_in_use"].astype(np.float32)),
+                put(c["resident"].astype(np.float32)),
+                put(c["demand_tps"].astype(np.float32)))
 
     def begin_tick(self, now: float) -> TickInputs:
         """Measurement + compact gather: live rows only, in slot order

@@ -37,10 +37,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.control_plane import CLASS_CODES, ControlState, bucket_width
+from repro.core.shard_plane import AXIS, store_mesh
 from repro.core.types import EntitlementState, EntitlementStatus, Resources
 
 #: EntitlementState <-> int8 codes for the ``state_code`` column.
@@ -246,6 +249,12 @@ class ResidentStore:
         return self._live_names
 
     # -- device mirror --------------------------------------------------------
+    def put_rows(self, rows: np.ndarray) -> jax.Array:
+        """Upload one full-width row column (a mirror column or a
+        per-tick kernel input) to where this store's rows live: the
+        default device for the flat store."""
+        return jnp.asarray(rows)
+
     def device_state(self) -> ControlState:
         """Kernel-facing ``ControlState`` over ALL slots (free slots are
         inert unbound rows).  Cached: rebuilt only after host-side
@@ -253,17 +262,9 @@ class ResidentStore:
         via :meth:`adopt_device`, so steady-state ticking re-uploads
         nothing."""
         if self._device is None:
-            c = self.col
-            self._device = ControlState(
-                class_code=jnp.asarray(c["class_code"]),
-                bound=jnp.asarray(c["bound"]),
-                baseline_tps=jnp.asarray(c["baseline_tps"]),
-                baseline_kv=jnp.asarray(c["baseline_kv"]),
-                baseline_conc=jnp.asarray(c["baseline_conc"]),
-                slo_ms=jnp.asarray(c["slo_ms"]),
-                burst=jnp.asarray(c["burst"]),
-                debt=jnp.asarray(c["debt"]),
-            )
+            self._device = ControlState(**{
+                f.name: self.put_rows(self.col[f.name])
+                for f in dataclasses.fields(ControlState)})
         return self._device
 
     def adopt_device(self, state: ControlState) -> None:
@@ -312,13 +313,6 @@ class ResidentStore:
         v.created_at = st.created_at
 
 
-def _state_block(state: ControlState, lo: int, hi: int) -> ControlState:
-    """Device-side row slice of a ``ControlState`` (views, no upload)."""
-    return ControlState(**{
-        f.name: getattr(state, f.name)[lo:hi]
-        for f in dataclasses.fields(ControlState)})
-
-
 class ShardedResidentStore(ResidentStore):
     """:class:`ResidentStore` partitioned into ``n_shards`` equal
     contiguous row blocks — the host-side half of the sharded control
@@ -330,11 +324,17 @@ class ShardedResidentStore(ResidentStore):
       * **per-shard free lists**: allocation picks the emptiest shard
         and recycles within it, so entitlement churn touches exactly
         one block and never crosses shards;
+      * **block placement**: the mirror is one array per column,
+        sharded ``P("rows")`` over :attr:`mesh` — block ``s`` lives on
+        the mesh device that owns it (device ``d`` holds the
+        ``n_shards / mesh.size`` consecutive blocks from ``d·k``), so
+        a sharded dispatch starts from rows already in place;
       * **block-granular mirror invalidation**: ``mark_dirty_slot``
         marks only the owning shard's block stale; ``device_state()``
-        re-uploads dirty blocks and concatenates them with the cached
-        clean ones device-side — attach/detach/migration of one row
-        re-uploads ``capacity/n_shards`` rows, not the pool
+        uploads dirty blocks straight to their owning device and
+        reassembles the sharded array from the per-device pieces —
+        attach/detach/migration of one row re-uploads
+        ``capacity/n_shards`` rows, not the pool
         (``block_uploads`` / ``full_uploads`` / ``uploaded_rows``
         counters pin this in tests);
       * **slot stability**: shards are equal blocks of the CURRENT
@@ -374,6 +374,16 @@ class ShardedResidentStore(ResidentStore):
 
     def shard_of(self, slot: int) -> int:
         return slot // self.shard_rows
+
+    @property
+    def mesh(self) -> Mesh:
+        """Placement mesh: the ``rows`` mesh over the largest pow2
+        device count ≤ ``n_shards`` (one device on a one-chip host) —
+        the same mesh ``shard_plane.pool_mesh`` dispatches on."""
+        return store_mesh(self.n_shards)
+
+    def put_rows(self, rows: np.ndarray) -> jax.Array:
+        return jax.device_put(rows, NamedSharding(self.mesh, P(AXIS)))
 
     def shard_of_name(self, name: str) -> int:
         """Owning shard of a resident entitlement (routing surface)."""
@@ -459,44 +469,64 @@ class ShardedResidentStore(ResidentStore):
     def mark_dirty_slot(self, slot: int) -> None:
         if self._device is not None:
             # split the (clean) full mirror into blocks before any goes
-            # stale — device-side slicing, no upload
-            rows = self.shard_rows
-            self._device_blocks = [
-                _state_block(self._device, s * rows, (s + 1) * rows)
-                for s in range(self.n_shards)]
+            # stale — slices of each device's own piece, no upload
+            self._device_blocks = self._split_blocks(self._device)
             self._device = None
         if self._device_blocks is None:
             return                             # fully dirty: next build is full
         self._dirty_shards.add(self.shard_of(slot))
+
+    def _split_blocks(self, state: ControlState) -> list[ControlState]:
+        """Per-shard blocks of a placed mirror, each on its owning
+        device."""
+        rows = self.shard_rows
+        per_dev = self.n_shards // self.mesh.size
+        blocks: list[dict] = [{} for _ in range(self.n_shards)]
+        for f in dataclasses.fields(ControlState):
+            for piece in getattr(state, f.name).addressable_shards:
+                first = (piece.index[0].start or 0) // rows
+                for j in range(per_dev):
+                    blocks[first + j][f.name] = \
+                        piece.data[j * rows:(j + 1) * rows]
+        return [ControlState(**b) for b in blocks]
+
+    def _assemble(self, blocks: list[ControlState]) -> ControlState:
+        """One ``P("rows")``-sharded array per column from per-shard
+        blocks that already sit on their owning devices."""
+        mesh = self.mesh
+        per_dev = self.n_shards // mesh.size
+        sharding = NamedSharding(mesh, P(AXIS))
+
+        def column(name: str) -> jax.Array:
+            pieces = [getattr(b, name) for b in blocks]
+            per_device = [
+                pieces[d * per_dev] if per_dev == 1 else
+                jnp.concatenate(pieces[d * per_dev:(d + 1) * per_dev])
+                for d in range(mesh.size)]
+            return jax.make_array_from_single_device_arrays(
+                (self.capacity,), sharding, per_device)
+
+        return ControlState(**{f.name: column(f.name)
+                               for f in dataclasses.fields(ControlState)})
 
     def device_state(self) -> ControlState:
         if self._device is not None:
             return self._device
         if self._device_blocks is not None:
             rows = self.shard_rows
+            devices = self.mesh.devices.flat
+            per_dev = self.n_shards // self.mesh.size
             c = self.col
             for s in sorted(self._dirty_shards):
                 lo = s * rows
-                self._device_blocks[s] = ControlState(
-                    class_code=jnp.asarray(c["class_code"][lo:lo + rows]),
-                    bound=jnp.asarray(c["bound"][lo:lo + rows]),
-                    baseline_tps=jnp.asarray(
-                        c["baseline_tps"][lo:lo + rows]),
-                    baseline_kv=jnp.asarray(c["baseline_kv"][lo:lo + rows]),
-                    baseline_conc=jnp.asarray(
-                        c["baseline_conc"][lo:lo + rows]),
-                    slo_ms=jnp.asarray(c["slo_ms"][lo:lo + rows]),
-                    burst=jnp.asarray(c["burst"][lo:lo + rows]),
-                    debt=jnp.asarray(c["debt"][lo:lo + rows]),
-                )
+                owner = devices[s // per_dev]
+                self._device_blocks[s] = ControlState(**{
+                    f.name: jax.device_put(c[f.name][lo:lo + rows], owner)
+                    for f in dataclasses.fields(ControlState)})
             self.block_uploads += len(self._dirty_shards)
             self.uploaded_rows += rows * len(self._dirty_shards)
             self._dirty_shards.clear()
-            blocks = self._device_blocks
-            self._device = ControlState(**{
-                f.name: jnp.concatenate(
-                    [getattr(b, f.name) for b in blocks])
-                for f in dataclasses.fields(ControlState)})
+            self._device = self._assemble(self._device_blocks)
             return self._device
         state = super().device_state()         # full (re)build
         self.full_uploads += 1
@@ -504,6 +534,14 @@ class ShardedResidentStore(ResidentStore):
         return state
 
     def adopt_device(self, state: ControlState) -> None:
+        # a sharded tick's output is already in place; a fleet tick's
+        # slice of the stacked output is moved onto the owning devices
+        sharding = NamedSharding(self.mesh, P(AXIS))
+        if not all(getattr(state, f.name).sharding.is_equivalent_to(
+                sharding, 1) for f in dataclasses.fields(ControlState)):
+            state = ControlState(**{
+                f.name: jax.device_put(getattr(state, f.name), sharding)
+                for f in dataclasses.fields(ControlState)})
         super().adopt_device(state)
         self._device_blocks = None             # blocks stale; resliced lazily
         self._dirty_shards.clear()

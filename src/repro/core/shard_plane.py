@@ -43,7 +43,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.control_plane import (
@@ -100,19 +99,22 @@ def shard_width(n_rows: int, mesh: Mesh) -> int:
     return max(bucket_width(n_rows), mesh.size)
 
 
+def store_mesh(n_shards: int) -> Mesh:
+    """The mesh an ``n_shards`` store places its row blocks on: the
+    largest pow2 device count that does not exceed the shard count, so
+    device blocks align with free-list shards (size 1 on one chip)."""
+    return row_mesh(min(_pow2_floor(len(jax.devices())), n_shards))
+
+
 def pool_mesh(pool) -> Optional[Mesh]:
     """The mesh a pool's tick/admission should dispatch on, or None to
     stay single-device: requires a ``ShardedResidentStore`` (per-shard
-    free lists keep churn device-local) and ≥2 devices; the mesh never
-    exceeds the store's shard count, so device blocks align with
-    free-list shards."""
-    shards = getattr(pool.store, "n_shards", 0)
-    if shards < 2:
+    free lists keep churn device-local) whose placement mesh spans ≥2
+    devices."""
+    mesh = getattr(pool.store, "mesh", None)
+    if mesh is None or mesh.size < 2:
         return None
-    size = min(_pow2_floor(len(jax.devices())), shards)
-    if size < 2:
-        return None
-    return row_mesh(size)
+    return mesh
 
 
 # -- the sharded tick ---------------------------------------------------------
@@ -140,11 +142,11 @@ def shard_tick(state: ControlState, capacity_tps: jax.Array,
                           axis_name=AXIS)
 
     row, rep = P(AXIS), P()
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(row, rep, row, row, row, row, rep),
         out_specs=(row, row, row),
-        check_rep=False,
+        check_vma=False,
     )(state, capacity_tps, measured_tps, used_kv, used_conc,
       demand_tps, avg_slo_ms)
 
@@ -224,16 +226,16 @@ def shard_admit_quantum(arr: ControlState,
 
     row, rep = P(AXIS), P()
     if weights is None:
-        gathered = shard_map(
+        gathered = jax.shard_map(
             partial(_gather_compute_block, coeff=coeff), mesh=mesh,
             in_specs=(row, row, row, row, rep, rep),
-            out_specs=rep, check_rep=False,
+            out_specs=rep, check_vma=False,
         )(arr, bucket_level, in_flight, kv_in_use, pool_avg_slo, req_ent)
     else:
-        gathered = shard_map(
+        gathered = jax.shard_map(
             _gather_block, mesh=mesh,
             in_specs=(row, row, row, row, row, rep),
-            out_specs=rep, check_rep=False,
+            out_specs=rep, check_vma=False,
         )(arr, bucket_level, in_flight, kv_in_use, weights, req_ent)
     (req_w, bound_g, class_g, bconc_g, bkv_g,
      bucket_g, infl_g, kv_g) = gathered
@@ -303,11 +305,11 @@ def shard_plan_fleet(current: jax.Array, lo: jax.Array, hi: jax.Array,
                           config=config)
 
     row = P(AXIS)
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=tuple([row] * 13),
         out_specs=tuple([row] * 5),
-        check_rep=False,
+        check_vma=False,
     )(current, lo, hi, per_tps, per_kv, per_conc,
       res_tps, res_kv, res_conc, demand_tps, ewma_prev, seeded,
       low_ticks)

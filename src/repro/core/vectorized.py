@@ -197,10 +197,11 @@ def arrays_from_pool(pool, now: float = 0.0
     fallback = np.where(c["eff_tps"] != 0.0, c["eff_tps"],
                         c["baseline_tps"].astype(np.float64))
     levels = pool.ledger.peek_levels(fallback, now)
+    put = pool.store.put_rows
     return (pool.store.device_state(),
-            jnp.asarray(levels.astype(np.float32)),
-            jnp.asarray(c["resident"].astype(np.int32)),
-            jnp.asarray(c["kv_in_use"].astype(np.float32)))
+            put(levels.astype(np.float32)),
+            put(c["resident"].astype(np.int32)),
+            put(c["kv_in_use"].astype(np.float32)))
 
 
 def running_min_live(pool) -> float:

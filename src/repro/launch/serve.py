@@ -23,6 +23,7 @@ from repro.core import (
     TokenPool,
 )
 from repro.gateway import Gateway
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import InferenceEngine, Request
 from repro.serving.request import latency_summary
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced(vocab_size=1024, num_layers=4)
     model = build_model(cfg)

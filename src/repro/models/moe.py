@@ -33,15 +33,6 @@ import jax.numpy as jnp
 from repro.models.layers import dense_init
 
 
-def _axis_size(name: str) -> int:
-    """Mapped-axis size; jax < 0.6 has no ``jax.lax.axis_size`` but
-    constant-folds ``psum(1, axis)`` to the same value."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.lax.psum(1, name)
-
-
 def init_moe(key, cfg, dtype) -> dict:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     E, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
@@ -134,7 +125,7 @@ def moe_mlp_ep(params: dict, x: jax.Array, cfg, ep_axes: tuple[str, ...],
     E, k = cfg.num_experts, cfg.experts_per_token
     n_ep = 1
     for a in ep_axes:
-        n_ep *= _axis_size(a)
+        n_ep *= jax.lax.axis_size(a)
     E_loc = E // n_ep
     C = max(1, int(T * k / E * cfg.moe_capacity_factor))
 

@@ -26,11 +26,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-try:                                 # jax ≥ 0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:               # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
 from repro.models import rglru as rglru_lib
@@ -158,7 +153,7 @@ def _apply_mlp(params: dict, x: jax.Array, cfg: ArchConfig, rt: Runtime
         }
         fn = functools.partial(moe_lib.moe_mlp_ep, cfg=cfg,
                                ep_axes=rt.ep_axes, tp_axis=rt.tp_axis)
-        out = _shard_map(
+        out = jax.shard_map(
             fn, mesh=rt.mesh,
             in_specs=(specs, P(rt.dp, None)),
             out_specs=P(rt.dp, None),

@@ -184,6 +184,43 @@ class TestContention:
         # guaranteed sails through (never rejected within r_e)
         assert ac.decide(req("g", "g1")).admitted
 
+    @pytest.mark.parametrize("slo", [200.0, 1000.00003, 1234.5678901,
+                                     29999.123])
+    def test_self_competition_denied_at_any_slo(self, slo):
+        """The entitlement that sets the threshold ties with itself
+        exactly, so the strict check 5 denies it — also when its SLO
+        target is not representable in the f32 ``slo_ms`` column (the
+        threshold reads the column; the priority once read the f64
+        spec, and for SLOs that f32 rounds up it strictly exceeded its
+        own threshold and was admitted while the kernel denied)."""
+        from repro.gateway import Gateway, QuantumRequest
+
+        def contended_pool():
+            pool = mkpool(tps=2e6, conc=2.0, max_r=3)
+            pool.add_entitlement(ent("e", ServiceClass.ELASTIC, 1e4,
+                                     conc=3, slo=slo))
+            pool.add_entitlement(ent("f", ServiceClass.ELASTIC, 1e4,
+                                     conc=3, slo=30000.0))
+            ac = AdmissionController(pool)
+            for rid in ("e1", "e2"):
+                assert ac.decide(req("e", rid)).admitted
+                pool.on_start(rid)
+            assert ac.decide(req("e", "e3")).admitted     # queued
+            assert pool.contended()
+            return pool, ac
+
+        pool, ac = contended_pool()
+        assert pool.admission_threshold() == pool.priority("e")
+        d = ac.decide(req("e", "e4"))
+        assert not d.admitted and d.reason == DenyReason.LOW_PRIORITY
+
+        pool, _ = contended_pool()
+        gw = Gateway(pool)
+        gw.register_key("k-e", "e")
+        quantum = gw.handle_quantum(
+            [QuantumRequest("k-e", f"q{i}", 64, 64) for i in range(2)], 0.0)
+        assert [r.reason for r in quantum] == [DenyReason.LOW_PRIORITY.value] * 2
+
     def test_threshold_is_min_live_entitlement_priority(self):
         pool = mkpool(conc=2.0)
         pool.add_entitlement(ent("s", ServiceClass.SPOT, 0.0, conc=8))

@@ -430,6 +430,103 @@ def mkpool(shards, n_ents=37, tps=2000.0, slots=64.0, name="p"):
     return pool
 
 
+def check_block_placement() -> dict:
+    """Place a sharded pool's mirror, dirty one block, and report where
+    every row block sits and whether the sharded kernels, fed straight
+    from the placed arrays, still equal the single-device kernels bit
+    for bit.  Run in-process and in a 4-device child (below)."""
+    from repro.core.vectorized import arrays_from_pool
+
+    pool = mkpool(4, n_ents=64)
+    pool.tick(1.0)
+    store = pool.store
+    mesh = store.mesh
+    devices = list(mesh.devices.flat)
+    rows = store.capacity // mesh.size
+
+    def misplaced() -> int:
+        state = store.device_state()
+        return sum(
+            int(piece.device != devices[(piece.index[0].start or 0) // rows]
+                or piece.data.shape[0] != rows)
+            for f in dataclasses.fields(ControlState)
+            for piece in getattr(state, f.name).addressable_shards)
+
+    store.mark_dirty()
+    full = misplaced()
+    uploads = store.block_uploads
+    pool.status["e5"].debt = 0.25              # dirties one block
+    block = misplaced()
+    uploads = store.block_uploads - uploads
+
+    one = jax.devices()[0]
+    state = store.device_state()
+    cols = [store.put_rows(store.col[k].astype(np.float32))
+            for k in ("measured_tps", "kv_in_use", "resident", "demand_tps")]
+    tick_args = (jnp.float32(pool.capacity().tokens_per_second), *cols,
+                 jnp.float32(pool.pool_avg_slo()))
+    got = shard_tick(state, *tick_args, mesh=mesh)
+    ref = control_tick(jax.device_put(state, one),
+                       *jax.device_put(tick_args, one))
+    same = lambda a, b: all(                                 # noqa: E731
+        np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+    tick_equal = same(ref, got)
+
+    snap = arrays_from_pool(pool, now=1.5)
+    rng = np.random.RandomState(3)
+    req = dict(
+        pool_in_flight=jnp.int32(0), pool_conc_cap=jnp.float32(64.0),
+        running_min_priority=jnp.float32(np.inf),
+        pool_avg_slo=jnp.float32(pool.pool_avg_slo()),
+        req_ent=jnp.asarray(rng.randint(0, 64, 32), jnp.int32),
+        req_tokens=jnp.asarray(rng.rand(32).astype(np.float32) * 80 + 1),
+        req_kv=jnp.zeros(32, jnp.float32))
+    got = shard_admit_quantum(*snap, **req, mesh=mesh)
+    ref = admit_quantum(*jax.device_put(snap, one), **req)
+    admit_equal = same(ref, got)
+    return {"mesh_size": mesh.size, "misplaced_full": full,
+            "misplaced_block": block, "block_uploads": uploads,
+            "tick_equal": tick_equal, "admit_equal": admit_equal}
+
+
+class TestBlockPlacement:
+    """Each row block of a ``ShardedResidentStore`` mirror lives on the
+    mesh device that owns it — after a full upload and after a single
+    dirty block is re-uploaded — and the sharded kernels run straight
+    from those placed arrays with decisions bitwise equal to the
+    single-device kernels."""
+
+    def assert_placed(self, rep: dict, mesh_size: int) -> None:
+        assert rep["mesh_size"] == mesh_size, rep
+        assert rep["misplaced_full"] == 0 and rep["misplaced_block"] == 0
+        assert rep["block_uploads"] == 1, rep
+        assert rep["tick_equal"] and rep["admit_equal"], rep
+
+    def test_visible_devices(self):
+        self.assert_placed(check_block_placement(),
+                           min(4, MESH_SIZES[-1]))
+
+    def test_four_forced_host_devices(self):
+        import json
+        import os
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.pathsep.join(
+                       [os.path.join(root, "src"), root]))
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import json; from tests.test_shard_plane import "
+             "check_block_placement as c; print(json.dumps(c()))"],
+            cwd=root, env=env, capture_output=True, text=True,
+            timeout=300, check=True)
+        self.assert_placed(json.loads(out.stdout.splitlines()[-1]), 4)
+
+
 class TestPoolIntegration:
     """A sharded pool (PoolSpec.shards) must tick and admit exactly
     like a flat pool, name for name, through the public surfaces."""
