@@ -1,0 +1,9 @@
+"""``admit_quantum_roofline``: share of its roofline that ``admit_quantum`` reached
+over the traced window (see ``_roofline.py``)."""
+from __future__ import annotations
+
+from bench.metrics._roofline import share
+
+
+def read(ctx):
+    return share(ctx, "admit_quantum")

@@ -1,0 +1,9 @@
+"""``quantum_ms``: mean wall time of the benchmark's ``quantum`` span over the
+window (the call's outputs are on the host when the span ends)."""
+from __future__ import annotations
+
+from bench.metrics._span import mean_ms
+
+
+def read(ctx):
+    return mean_ms(ctx, "quantum")
