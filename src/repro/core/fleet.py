@@ -62,6 +62,7 @@ from repro.core.autoscaler import ScaleDecision, replicas_for
 from repro.core.markers import kernel
 from repro.core.pool import TickRecord, TokenPool
 from repro.core.types import Resources, ServiceClass
+from repro.telemetry.spans import child, moved_to_device, readback
 
 #: Reason codes emitted by :func:`plan_fleet` (index = code), matching
 #: the scalar ``Autoscaler.plan`` reason strings.
@@ -286,7 +287,8 @@ class FleetPlanner:
              records: Optional[dict[str, TickRecord]] = None,
              now: float = 0.0) -> FleetPlan:
         """One planning round over the fleet: ONE ``plan_fleet``
-        dispatch + the Python-side rebalance pass."""
+        dispatch (the ``fleet.kernel`` span, upload through readback)
+        + the Python-side rebalance pass (``fleet.rebalance``)."""
         records = records or {}
         self._plans += 1
         # drop state of pools that left the fleet
@@ -296,14 +298,11 @@ class FleetPlanner:
             return FleetPlan(decisions={}, migrations=[],
                              unmet_replicas={})
         names, arr = self._arrays(pools, records)
-        desired, reason, ewma, low, need = plan_fleet(
-            **{k: jnp.asarray(v) for k, v in arr.items()},
-            config=self.config)
-        desired = np.asarray(desired)
-        reason = np.asarray(reason)
-        ewma = np.asarray(ewma)
-        low = np.asarray(low)
-        need = np.asarray(need)
+        with child("fleet.kernel"):
+            dev = {k: jnp.asarray(v) for k, v in arr.items()}
+            moved_to_device(sum(x.nbytes for x in dev.values()))
+            out = plan_fleet(**dev, config=self.config)
+            desired, reason, ewma, low, need = (readback(x) for x in out)
 
         decisions: dict[str, ScaleDecision] = {}
         unmet: dict[str, float] = {}
@@ -320,7 +319,8 @@ class FleetPlanner:
             over = float(need[i]) - float(arr["hi"][i])
             if over > 1e-6:
                 unmet[name] = over
-        migrations = self._rebalance(pools, records, names, need, arr)
+        with child("fleet.rebalance"):
+            migrations = self._rebalance(pools, records, names, need, arr)
         return FleetPlan(decisions=decisions, migrations=migrations,
                          unmet_replicas=unmet)
 

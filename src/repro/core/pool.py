@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from collections import deque
 from typing import Optional
 
@@ -68,6 +67,7 @@ from repro.core.types import (
     ServiceClass,
 )
 from repro.core.virtual_node import LeasePod, VirtualNodeProvider
+from repro.telemetry.spans import child, readback, span
 
 __all__ = [  # noqa: F822 — InFlight re-exported from request_table
     "EntitlementMigration", "InFlight", "SettleBatch", "TickInputs",
@@ -298,10 +298,9 @@ class TokenPool:
         self.history: deque = deque(maxlen=spec.history_maxlen)
         self._last_tick = now
         #: optional ``repro.telemetry.Telemetry`` sink (set by
-        #: ``Telemetry.attach_pool``); when present every tick emits a
-        #: duration sample + water-fill/debt gauges + a trace slice
+        #: ``Telemetry.attach_pool``); when present every tick records
+        #: its spans + water-fill/debt gauges
         self.telemetry = None
-        self._tick_t0 = 0.0
         #: TTL deadlines for the (rare) entitlements that declare one —
         #: expiry scans these, not the whole membership
         self._ttl_deadline: dict[str, float] = {}
@@ -1056,7 +1055,6 @@ class TokenPool:
         interval retains exactly ½, the historical fixed blend, while
         irregular tick spacing now yields a tick-rate-independent time
         constant."""
-        self._tick_t0 = time.perf_counter()
         dt = max(1e-9, now - self._last_tick)
         self._last_tick = now
         self.expire_entitlements(now)
@@ -1162,8 +1160,8 @@ class TokenPool:
         if adopt_device:
             s.adopt_device(new_state)
         else:
-            c["burst"][:] = np.asarray(new_state.burst)
-            c["debt"][:] = np.asarray(new_state.debt)
+            c["burst"][:] = readback(new_state.burst)
+            c["debt"][:] = readback(new_state.debt)
             s.mark_dirty()
         alive = c["alive"]
         alloc64 = np.asarray(alloc, np.float64)
@@ -1181,11 +1179,10 @@ class TokenPool:
         )
         self.history.append(rec)
         if self.telemetry is not None:
-            # once per tick (O(pools), not O(requests)): duration +
-            # water-fill/debt totals into the registry + trace timeline
+            # once per tick (O(pools), not O(requests)): water-fill/debt
+            # totals into the registry + trace timeline
             self.telemetry.on_tick(
                 self.spec.name, now,
-                time.perf_counter() - self._tick_t0,
                 alloc_total=float(alloc64[idx].sum()),
                 debt_total=float(c["debt"][idx].sum()),
                 in_flight=int(c["in_flight"][idx].sum()))
@@ -1198,25 +1195,34 @@ class TokenPool:
         kernel dispatch at the store's (pow2) width → vectorized
         absorb.  Free slots ride along as inert unbound rows, so
         entitlement churn within a capacity bucket never retraces the
-        jitted kernel."""
-        self._measure(now)
-        measured, used_kv, used_conc, demand = self._kernel_inputs()
-        mesh = shard_plane.pool_mesh(self)
-        if mesh is None:
-            new_state, alloc, weights = control_plane.control_tick(
-                self.store.device_state(),
-                jnp.float32(self.capacity().tokens_per_second),
-                measured, used_kv, used_conc, demand,
-                jnp.float32(self.pool_avg_slo()),
-                coeff=self.spec.coefficients)
-        else:
-            # sharded dispatch — bit-identical decisions (the tick's
-            # tree reductions decompose exactly across mesh blocks)
-            new_state, alloc, weights = shard_plane.shard_tick(
-                self.store.device_state(),
-                jnp.float32(self.capacity().tokens_per_second),
-                measured, used_kv, used_conc, demand,
-                jnp.float32(self.pool_avg_slo()),
-                coeff=self.spec.coefficients, mesh=mesh)
-        return self._absorb_tick(now, new_state, np.asarray(alloc),
-                                 np.asarray(weights))
+        jitted kernel.  Spans: ``pool.tick`` around ``pool.measure``,
+        ``pool.kernel`` (dispatch through the readback) and
+        ``pool.absorb``."""
+        name = self.spec.name
+        with span(self.telemetry, "pool.tick", pool=name, now=now):
+            with child("pool.measure"):
+                self._measure(now)
+                measured, used_kv, used_conc, demand = \
+                    self._kernel_inputs()
+            with child("pool.kernel"):
+                mesh = shard_plane.pool_mesh(self)
+                if mesh is None:
+                    new_state, alloc, weights = control_plane.control_tick(
+                        self.store.device_state(),
+                        jnp.float32(self.capacity().tokens_per_second),
+                        measured, used_kv, used_conc, demand,
+                        jnp.float32(self.pool_avg_slo()),
+                        coeff=self.spec.coefficients)
+                else:
+                    # sharded dispatch — bit-identical decisions (the
+                    # tick's tree reductions decompose exactly across
+                    # mesh blocks)
+                    new_state, alloc, weights = shard_plane.shard_tick(
+                        self.store.device_state(),
+                        jnp.float32(self.capacity().tokens_per_second),
+                        measured, used_kv, used_conc, demand,
+                        jnp.float32(self.pool_avg_slo()),
+                        coeff=self.spec.coefficients, mesh=mesh)
+                alloc, weights = readback(alloc), readback(weights)
+            with child("pool.absorb"):
+                return self._absorb_tick(now, new_state, alloc, weights)

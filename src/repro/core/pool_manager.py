@@ -41,6 +41,7 @@ from repro.core.markers import hot_path
 from repro.core.pool import InFlight, TickRecord, TokenPool
 from repro.core.types import EntitlementSpec, PoolSpec
 from repro.core.virtual_node import VirtualNodeProvider
+from repro.telemetry.spans import child, moved_to_device, readback, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -350,6 +351,18 @@ class PoolManager:
                 pool = group[0]
                 records[pool.spec.name] = pool.tick(now)
                 continue
+            with span(group[0].telemetry, "pool.tick", now=now):
+                records.update(self._tick_group(coeff, group, now))
+        return records
+
+    @hot_path
+    def _tick_group(self, coeff, group: list[TokenPool],
+                    now: float) -> dict[str, TickRecord]:
+        """One ``control_tick_pools`` dispatch over a group of pools that
+        share coefficients: ``pool.measure`` (window folds, stacked
+        uploads), ``pool.kernel`` (dispatch through the readback) and
+        ``pool.absorb`` (each pool adopts its slice)."""
+        with child("pool.measure"):
             for p in group:
                 p._measure(now)
             # Store capacities are already powers of two; the group
@@ -362,6 +375,7 @@ class PoolManager:
                 out = np.zeros((len(group), width), np.float32)
                 for i, p in enumerate(group):
                     out[i, :p.store.capacity] = p.store.col[k]
+                moved_to_device(out.nbytes)
                 return jnp.asarray(out)
 
             members = tuple(p.spec.name for p in group)
@@ -390,24 +404,28 @@ class PoolManager:
                 states = control_plane.stack_states(
                     [p.store.device_state() for p in group], width=width)
                 self.stack_restacks += len(group)
+            measured, used_kv, used_conc, demand = (
+                padded("measured_tps"), padded("kv_in_use"),
+                padded("resident"), padded("demand_tps"))
+        with child("pool.kernel"):
             new_state, alloc, weights = control_plane.control_tick_pools(
                 states,
                 jnp.asarray([p.capacity().tokens_per_second
                              for p in group], jnp.float32),
-                padded("measured_tps"),
-                padded("kv_in_use"),
-                padded("resident"),
-                padded("demand_tps"),
+                measured, used_kv, used_conc, demand,
                 jnp.asarray([p.pool_avg_slo() for p in group],
                             jnp.float32),
                 coeff=coeff)
-            burst = np.asarray(new_state.burst)
-            debt = np.asarray(new_state.debt)
-            alloc = np.asarray(alloc)
-            weights = np.asarray(weights)
+            burst = readback(new_state.burst)
+            debt = readback(new_state.debt)
+            alloc = readback(alloc)
+            weights = readback(weights)
+        records: dict[str, TickRecord] = {}
+        with child("pool.absorb"):
             sources: list[ControlState] = []
             for k, pool in enumerate(group):
                 w = pool.store.capacity
+                moved_to_device(burst[k, :w].nbytes + debt[k, :w].nbytes)
                 sliced = ControlState(
                     class_code=new_state.class_code[k, :w],
                     bound=new_state.bound[k, :w],
@@ -430,7 +448,6 @@ class PoolManager:
                 "members": members, "width": width,
                 "stacked": new_state, "sources": sources}
         return records
-
 
     # -- fleet capacity planning -------------------------------------------------
     def migrate_entitlement(self, name: str, src: str, dst: str,

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.control_plane import CLASS_CODES, ControlState, bucket_width
 from repro.core.shard_plane import AXIS, store_mesh
 from repro.core.types import EntitlementState, EntitlementStatus, Resources
+from repro.telemetry.spans import moved_to_device, readback
 
 #: EntitlementState <-> int8 codes for the ``state_code`` column.
 STATE_CODES: dict[EntitlementState, int] = {
@@ -252,7 +253,9 @@ class ResidentStore:
     def put_rows(self, rows: np.ndarray) -> jax.Array:
         """Upload one full-width row column (a mirror column or a
         per-tick kernel input) to where this store's rows live: the
-        default device for the flat store."""
+        default device for the flat store.  Its bytes are charged to
+        the innermost open span."""
+        moved_to_device(rows.nbytes)
         return jnp.asarray(rows)
 
     def device_state(self) -> ControlState:
@@ -269,9 +272,9 @@ class ResidentStore:
 
     def adopt_device(self, state: ControlState) -> None:
         """Adopt a tick's output state as the device mirror and sync the
-        numpy burst/debt columns from it (two C-speed copies)."""
-        self.col["burst"][:] = np.asarray(state.burst)
-        self.col["debt"][:] = np.asarray(state.debt)
+        numpy burst/debt columns from it (two readbacks)."""
+        self.col["burst"][:] = readback(state.burst)
+        self.col["debt"][:] = readback(state.debt)
         self._device = state
 
     # -- row <-> EntitlementStatus --------------------------------------------
@@ -383,6 +386,7 @@ class ShardedResidentStore(ResidentStore):
         return store_mesh(self.n_shards)
 
     def put_rows(self, rows: np.ndarray) -> jax.Array:
+        moved_to_device(rows.nbytes)
         return jax.device_put(rows, NamedSharding(self.mesh, P(AXIS)))
 
     def shard_of_name(self, name: str) -> int:
@@ -523,6 +527,9 @@ class ShardedResidentStore(ResidentStore):
                 self._device_blocks[s] = ControlState(**{
                     f.name: jax.device_put(c[f.name][lo:lo + rows], owner)
                     for f in dataclasses.fields(ControlState)})
+                moved_to_device(sum(c[f.name][lo:lo + rows].nbytes
+                                    for f in dataclasses.fields(
+                                        ControlState)))
             self.block_uploads += len(self._dirty_shards)
             self.uploaded_rows += rows * len(self._dirty_shards)
             self._dirty_shards.clear()

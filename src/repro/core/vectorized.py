@@ -43,6 +43,7 @@ from repro.core.control_plane import (
 )
 from repro.core.markers import kernel
 from repro.core.types import PriorityCoefficients, ServiceClass
+from repro.telemetry.spans import child, moved_to_device, readback
 
 #: Back-compat name: the array-of-rows state is the ControlState.
 PoolArrays = ControlState
@@ -236,7 +237,9 @@ def _running_min_f32(pool, weights: jax.Array,
     rows = pool.inflight_owner_slots()
     if not rows.size:
         return float("inf")
-    return float(jnp.min(weights[jnp.asarray(rows, jnp.int32)]))
+    idx = jnp.asarray(rows, jnp.int32)
+    moved_to_device(idx.nbytes)
+    return float(readback(jnp.min(weights[idx])))
 
 
 @dataclasses.dataclass
@@ -267,23 +270,26 @@ def quantum_snapshot(pool, now: float) -> QuantumSnapshot:
     views of the pool's resident arrays — no per-row Python gather
     (the name→slot map and name list are C-speed container copies, so
     a held snapshot stays internally consistent even if membership
-    churns after it was taken)."""
-    state, levels, infl, kvu = arrays_from_pool(pool, now)
-    row_of = dict(pool.store.slot_of)
-    avg_slo = float(pool.pool_avg_slo())
-    weights = priority_batch(state, jnp.float32(avg_slo),
-                             pool.spec.coefficients)
-    return QuantumSnapshot(
-        names=list(pool.store.live_names()),
-        row_of=row_of,
-        state=state,
-        bucket_level=levels,
-        in_flight=infl,
-        kv_in_use=kvu,
-        weights=weights,
-        pool_in_flight=pool.pool_in_flight(),
-        pool_resident=pool.total_resident(),
-        pool_conc_cap=float(pool.capacity().concurrency),
-        running_min_priority=_running_min_f32(pool, weights, row_of),
-        pool_avg_slo=avg_slo,
-    )
+    churns after it was taken).  Inside a quantum it is the
+    ``gateway.snapshot`` span, which ends on the owner gather's
+    readback."""
+    with child("gateway.snapshot", pool.spec.name):
+        state, levels, infl, kvu = arrays_from_pool(pool, now)
+        row_of = dict(pool.store.slot_of)
+        avg_slo = float(pool.pool_avg_slo())
+        weights = priority_batch(state, jnp.float32(avg_slo),
+                                 pool.spec.coefficients)
+        return QuantumSnapshot(
+            names=list(pool.store.live_names()),
+            row_of=row_of,
+            state=state,
+            bucket_level=levels,
+            in_flight=infl,
+            kv_in_use=kvu,
+            weights=weights,
+            pool_in_flight=pool.pool_in_flight(),
+            pool_resident=pool.total_resident(),
+            pool_conc_cap=float(pool.capacity().concurrency),
+            running_min_priority=_running_min_f32(pool, weights, row_of),
+            pool_avg_slo=avg_slo,
+        )

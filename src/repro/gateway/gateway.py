@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from itertools import chain
 from operator import attrgetter
 from typing import NamedTuple, Optional, Sequence, Union
@@ -61,6 +60,7 @@ from repro.core import shard_plane
 from repro.core.pool_manager import PoolOrManager, as_manager
 from repro.core.vectorized import admit_quantum, quantum_snapshot
 from repro.telemetry import flight as flightrec
+from repro.telemetry.spans import child, moved_to_device, readback, span
 
 #: C-speed attribute extractors for the quantum fast path.
 _Q_RID = attrgetter("request_id")
@@ -148,8 +148,12 @@ class Gateway:
             for name, pool in self.manager.pools.items()}
         # ``telemetry=True`` builds a fresh ``repro.telemetry.Telemetry``;
         # passing an instance shares one plane across gateways.  Off by
-        # default: the overhead gate in BENCH_admission.json pins the
-        # telemetry-on quantum path within 5% of this zero-cost default.
+        # default; on, it also records the quantum's, tick's and plan's
+        # spans (``repro.telemetry.spans``): 3–7 µs a span and ~35 µs a
+        # root call on a CPU host, ~1000 spans a minute at 4000
+        # requests/s.  On a TPU v5e host the admission median with them
+        # stayed within the run-to-run spread of the same gateway with
+        # its span sites stubbed out.
         if telemetry is True:
             from repro.telemetry import Telemetry
             telemetry = Telemetry()
@@ -346,69 +350,80 @@ class Gateway:
         evaluated once at quantum start (per key + token shape), not
         re-ranked between requests mid-quantum.
         """
-        if len(requests) == 1:
-            # A one-request quantum replays the sequential walk exactly
-            # (per-pool batches of size one) — skip the snapshot +
-            # kernel dispatch and use the scalar pipeline directly.
-            q = requests[0]
-            return [self.handle(q.api_key, q.request_id, q.input_tokens,
-                                q.max_tokens, now,
-                                kv_bytes_per_token=q.kv_bytes_per_token)]
         tel = self.telemetry
-        t0 = time.perf_counter() if tel is not None else 0.0
-        fast = (self._quantum_fast(requests, now)
-                if self.quantum_fast_enabled else None)
-        if fast is not None:
-            if tel is not None:
-                tel.on_quantum(now, len(requests),
-                               time.perf_counter() - t0)
-            return fast
+        with span(tel, "gateway.quantum", now=now):
+            if len(requests) == 1:
+                # A one-request quantum replays the sequential walk
+                # exactly (per-pool batches of size one) — skip the
+                # snapshot + kernel dispatch and use the scalar pipeline
+                # directly.
+                q = requests[0]
+                responses = [self.handle(
+                    q.api_key, q.request_id, q.input_tokens, q.max_tokens,
+                    now, kv_bytes_per_token=q.kv_bytes_per_token)]
+            else:
+                responses = (self._quantum_fast(requests, now)
+                             if self.quantum_fast_enabled else None)
+                if responses is None:
+                    responses = self._quantum_rounds(requests, now)
+        if tel is not None:
+            tel.on_quantum(len(requests))
+        return responses
+
+    @hot_path
+    def _quantum_rounds(self, requests: Sequence[QuantumRequest],
+                        now: float) -> list[GatewayResponse]:
+        """The generic leg-round loop of :meth:`handle_quantum`."""
+        tel = self.telemetry
         responses: list[Optional[GatewayResponse]] = [None] * len(requests)
-        # Routes are resolved once per distinct (key, token shape) at
-        # quantum start — within a quantum `now` is fixed, so a key's
-        # route (and its headroom ordering) is a constant.
-        route_cache: dict[tuple, Optional[list]] = {}
-        pending: list[_Pending] = []
-        unknown_ids: list[str] = []
-        for i, q in enumerate(requests):
-            ck = (q.api_key, q.input_tokens, q.max_tokens)
-            legs = route_cache.get(ck, False)
-            if legs is False:
-                route = self.route(q.api_key, now)
-                legs = None if route is None else \
-                    self.manager.route_order_indexed(
-                        list(route), q.input_tokens, q.max_tokens, now,
-                        policy=self.spill_policy)
-                route_cache[ck] = legs
-            if legs is None:
-                responses[i] = GatewayResponse(
-                    status=401, request_id=q.request_id,
-                    reason="unknown_key")
-                unknown_ids.append(q.request_id)
-                continue
-            pending.append(_Pending(idx=i, req=q, legs=list(legs)))
+        with child("gateway.route"):
+            # Routes are resolved once per distinct (key, token shape)
+            # at quantum start — within a quantum `now` is fixed, so a
+            # key's route (and its headroom ordering) is a constant.
+            route_cache: dict[tuple, Optional[list]] = {}
+            pending: list[_Pending] = []
+            unknown_ids: list[str] = []
+            for i, q in enumerate(requests):
+                ck = (q.api_key, q.input_tokens, q.max_tokens)
+                legs = route_cache.get(ck, False)
+                if legs is False:
+                    route = self.route(q.api_key, now)
+                    legs = None if route is None else \
+                        self.manager.route_order_indexed(
+                            list(route), q.input_tokens, q.max_tokens,
+                            now, policy=self.spill_policy)
+                    route_cache[ck] = legs
+                if legs is None:
+                    responses[i] = GatewayResponse(
+                        status=401, request_id=q.request_id,
+                        reason="unknown_key")
+                    unknown_ids.append(q.request_id)
+                    continue
+                pending.append(_Pending(idx=i, req=q, legs=list(legs)))
         if tel is not None and unknown_ids:
-            tel.record_terminal(now, unknown_ids,
-                                flightrec.VERDICT_UNKNOWN_KEY,
-                                flightrec.REASON_NONE)
+            with child("gateway.record"):
+                tel.record_terminal(now, unknown_ids,
+                                    flightrec.VERDICT_UNKNOWN_KEY,
+                                    flightrec.REASON_NONE)
 
         while pending:
             # spills from different pools (and espec-miss skips) land in
             # group order — restore arrival order so every pool batch
             # replays its requests exactly as the scalar loop would
             pending.sort(key=lambda p: p.idx)
+            done = [p for p in pending if p.leg_ptr >= len(p.legs)]
+            if done:
+                with child("gateway.deny"):
+                    for p in done:
+                        responses[p.idx] = self._finish_denied(p, now)
             groups: dict[str, list[_Pending]] = {}
             for p in pending:
-                if p.leg_ptr >= len(p.legs):
-                    responses[p.idx] = self._finish_denied(p, now)
-                else:
+                if p.leg_ptr < len(p.legs):
                     groups.setdefault(p.current()[1].pool, []).append(p)
             pending = []
             for pool_name, batch in groups.items():
                 pending.extend(self._admit_batch(pool_name, batch,
                                                  responses, now))
-        if tel is not None:
-            tel.on_quantum(now, len(requests), time.perf_counter() - t0)
         return responses
 
     def _finish_denied(self, p: _Pending, now: float) -> GatewayResponse:
@@ -439,41 +454,49 @@ class Gateway:
         """ONE padded ``admit_quantum`` dispatch for a pool batch of
         ``m`` live requests in replay order (``rows``/``tokens``/
         ``kvs`` may be lists or arrays).  Returns host-side
-        (admitted, reasons, weights) trimmed to the live prefix."""
-        width = quantum_width(m)
-        row_width = bucket_width(snap.state.n_rows)
+        (admitted, reasons, weights) trimmed to the live prefix: the
+        ``gateway.admit`` span."""
+        with child("gateway.admit", pool.spec.name):
+            width = quantum_width(m)
+            row_width = bucket_width(snap.state.n_rows)
 
-        def padvec(xs, dtype):
-            a = np.zeros(width, dtype)
-            a[:m] = xs
-            return a
+            def padvec(xs, dtype):
+                a = np.zeros(width, dtype)
+                a[:m] = xs
+                return a
 
-        live = np.zeros(width, bool)
-        live[:m] = True
-        mesh = shard_plane.pool_mesh(pool)
-        admit_kw = {} if mesh is None else {"mesh": mesh}
-        admit_fn = admit_quantum if mesh is None \
-            else shard_plane.shard_admit_quantum
-        admitted, reasons, req_w = admit_fn(
-            pad_state(snap.state, row_width),
-            pad_rows(snap.bucket_level, row_width),
-            pad_rows(snap.in_flight, row_width),
-            pad_rows(snap.kv_in_use, row_width),
-            pool_in_flight=jnp.int32(snap.pool_in_flight),
-            pool_conc_cap=jnp.float32(snap.pool_conc_cap),
-            running_min_priority=jnp.float32(snap.running_min_priority),
-            pool_avg_slo=jnp.float32(snap.pool_avg_slo),
-            req_ent=padvec(rows, np.int32),
-            req_tokens=padvec(tokens, np.float32),
-            req_kv=padvec(kvs, np.float32),
-            pool_resident=jnp.int32(snap.pool_resident),
-            req_live=live,
-            weights=pad_rows(snap.weights, row_width),
-            coeff=pool.spec.coefficients,
-            slack=pool.spec.admission_slack,
-            **admit_kw)
-        return (np.asarray(admitted)[:m], np.asarray(reasons)[:m],
-                np.asarray(req_w)[:m])
+            live = np.zeros(width, bool)
+            live[:m] = True
+            req_ent = padvec(rows, np.int32)
+            req_tokens = padvec(tokens, np.float32)
+            req_kv = padvec(kvs, np.float32)
+            moved_to_device(live.nbytes + req_ent.nbytes
+                            + req_tokens.nbytes + req_kv.nbytes)
+            mesh = shard_plane.pool_mesh(pool)
+            admit_kw = {} if mesh is None else {"mesh": mesh}
+            admit_fn = admit_quantum if mesh is None \
+                else shard_plane.shard_admit_quantum
+            admitted, reasons, req_w = admit_fn(
+                pad_state(snap.state, row_width),
+                pad_rows(snap.bucket_level, row_width),
+                pad_rows(snap.in_flight, row_width),
+                pad_rows(snap.kv_in_use, row_width),
+                pool_in_flight=jnp.int32(snap.pool_in_flight),
+                pool_conc_cap=jnp.float32(snap.pool_conc_cap),
+                running_min_priority=jnp.float32(
+                    snap.running_min_priority),
+                pool_avg_slo=jnp.float32(snap.pool_avg_slo),
+                req_ent=req_ent,
+                req_tokens=req_tokens,
+                req_kv=req_kv,
+                pool_resident=jnp.int32(snap.pool_resident),
+                req_live=live,
+                weights=pad_rows(snap.weights, row_width),
+                coeff=pool.spec.coefficients,
+                slack=pool.spec.admission_slack,
+                **admit_kw)
+            return (readback(admitted)[:m], readback(reasons)[:m],
+                    readback(req_w)[:m])
 
     @hot_path
     def _quantum_fast(self, requests: Sequence[QuantumRequest],
@@ -495,64 +518,68 @@ class Gateway:
         Returns None — before touching ANY state — when some key's
         route has several live legs; the generic loop takes over."""
         n = len(requests)
-        by_ck: dict[tuple, list[int]] = {}
-        for i, q in enumerate(requests):
-            ck = (q.api_key, q.input_tokens, q.max_tokens)
-            try:
-                by_ck[ck].append(i)
-            except KeyError:
-                by_ck[ck] = [i]
-        # resolve every distinct key first — pure reads, so the
-        # multi-leg bail-out leaves no partial state behind
-        resolved = []
-        for ck, idxs in by_ck.items():
-            key, inp, mx = ck
-            route = self.route(key, now)
-            legs = None if route is None else \
-                self.manager.route_order_indexed(
-                    list(route), inp, mx, now, policy=self.spill_policy)
-            if legs is not None and len(legs) > 1:
-                return None
-            resolved.append((idxs, ck, legs))
-        responses: list[Optional[GatewayResponse]] = [None] * n
-        pools: dict[str, list] = {}
         tel = self.telemetry
-        unknown_ids: list[str] = []
-        unroutable_ids: list[str] = []
-        unroutable_incr: dict[str, float] = {}
-        for idxs, ck, legs in resolved:
-            key, inp, mx = ck
-            if legs is None:
-                for i in idxs:
-                    responses[i] = GatewayResponse(
-                        status=401, request_id=requests[i].request_id,
-                        reason="unknown_key")
-                    unknown_ids.append(requests[i].request_id)
-            elif not legs:               # route exists, no live pool
-                for i in idxs:
-                    responses[i] = GatewayResponse(
-                        status=429, request_id=requests[i].request_id,
-                        retry_after_s=5.0,
-                        reason=DenyReason.POOL_UNAVAILABLE.value)
-                    unroutable_ids.append(requests[i].request_id)
-                unroutable_incr[f"unroutable:{key}"] = \
-                    unroutable_incr.get(f"unroutable:{key}", 0.0) \
-                    + float(len(idxs))
-            else:
-                hop, leg = legs[0]
-                pools.setdefault(leg.pool, []).append(
-                    (idxs, key, leg.entitlement, inp, mx, hop))
-        if unroutable_incr:
-            self.store.incr_many(unroutable_incr, now)
-        if tel is not None:
-            if unknown_ids:
-                tel.record_terminal(now, unknown_ids,
-                                    flightrec.VERDICT_UNKNOWN_KEY,
-                                    flightrec.REASON_NONE)
-            if unroutable_ids:
-                tel.record_terminal(now, unroutable_ids,
-                                    flightrec.VERDICT_DENY,
-                                    flightrec.REASON_POOL_UNAVAILABLE)
+        with child("gateway.route"):
+            by_ck: dict[tuple, list[int]] = {}
+            for i, q in enumerate(requests):
+                ck = (q.api_key, q.input_tokens, q.max_tokens)
+                try:
+                    by_ck[ck].append(i)
+                except KeyError:
+                    by_ck[ck] = [i]
+            # resolve every distinct key first — pure reads, so the
+            # multi-leg bail-out leaves no partial state behind
+            resolved = []
+            for ck, idxs in by_ck.items():
+                key, inp, mx = ck
+                route = self.route(key, now)
+                legs = None if route is None else \
+                    self.manager.route_order_indexed(
+                        list(route), inp, mx, now,
+                        policy=self.spill_policy)
+                if legs is not None and len(legs) > 1:
+                    return None
+                resolved.append((idxs, ck, legs))
+            responses: list[Optional[GatewayResponse]] = [None] * n
+            pools: dict[str, list] = {}
+            unknown_ids: list[str] = []
+            unroutable_ids: list[str] = []
+            unroutable_incr: dict[str, float] = {}
+            for idxs, ck, legs in resolved:
+                key, inp, mx = ck
+                if legs is None:
+                    for i in idxs:
+                        responses[i] = GatewayResponse(
+                            status=401, request_id=requests[i].request_id,
+                            reason="unknown_key")
+                        unknown_ids.append(requests[i].request_id)
+                elif not legs:               # route exists, no live pool
+                    for i in idxs:
+                        responses[i] = GatewayResponse(
+                            status=429, request_id=requests[i].request_id,
+                            retry_after_s=5.0,
+                            reason=DenyReason.POOL_UNAVAILABLE.value)
+                        unroutable_ids.append(requests[i].request_id)
+                    unroutable_incr[f"unroutable:{key}"] = \
+                        unroutable_incr.get(f"unroutable:{key}", 0.0) \
+                        + float(len(idxs))
+                else:
+                    hop, leg = legs[0]
+                    pools.setdefault(leg.pool, []).append(
+                        (idxs, key, leg.entitlement, inp, mx, hop))
+        if unroutable_incr or unknown_ids:
+            with child("gateway.record"):
+                if unroutable_incr:
+                    self.store.incr_many(unroutable_incr, now)
+                if tel is not None:
+                    if unknown_ids:
+                        tel.record_terminal(now, unknown_ids,
+                                            flightrec.VERDICT_UNKNOWN_KEY,
+                                            flightrec.REASON_NONE)
+                    if unroutable_ids:
+                        tel.record_terminal(
+                            now, unroutable_ids, flightrec.VERDICT_DENY,
+                            flightrec.REASON_POOL_UNAVAILABLE)
         for pool_name, entries in pools.items():
             self._admit_batch_fast(pool_name, entries, requests,
                                    responses, now)
@@ -617,18 +644,20 @@ class Gateway:
             counts.append(len(idxs))
             idx_lists.append(idxs)
         if tel is not None and nb_rids:
-            tel.record_decisions(
-                pool_name, now, nb_rids,
-                np.full(len(nb_rids), -1, np.int64),
-                np.asarray(nb_hops, np.int64),
-                np.zeros(len(nb_rids), bool),
-                np.full(len(nb_rids), 1, np.int16),   # NOT_BOUND
-                0.0, float(snap.running_min_priority)
-                * (1.0 - pool.spec.admission_slack),
-                np.asarray(nb_toks, np.float64))
+            with child("gateway.record", pool_name):
+                tel.record_decisions(
+                    pool_name, now, nb_rids,
+                    np.full(len(nb_rids), -1, np.int64),
+                    np.asarray(nb_hops, np.int64),
+                    np.zeros(len(nb_rids), bool),
+                    np.full(len(nb_rids), 1, np.int16),   # NOT_BOUND
+                    0.0, float(snap.running_min_priority)
+                    * (1.0 - pool.spec.admission_slack),
+                    np.asarray(nb_toks, np.float64))
         if not counts:
             if incr_acc:
-                store.incr_many(incr_acc, now)
+                with child("gateway.record", pool_name):
+                    store.incr_many(incr_acc, now)
             return
         # per-group constants expand to per-request arrays by GATHER,
         # not per-group np.full loops; argsort restores arrival order
@@ -662,115 +691,118 @@ class Gateway:
         admitted, reasons, req_w = self._dispatch_admit(
             pool, snap, rows64, toks64, kvs64, m)
 
-        ledger = pool.ledger
-        js = np.flatnonzero(admitted)
-        charged = np.zeros(m, bool)
-        ch_slots = np.empty(0, np.int64)
-        charge_ids: list[str] = []
-        if js.size:
-            # buckets ensured once per group with kernel admits (the
-            # same entitlement set the generic pass-1 loop ensures),
-            # vectorized: rates come off the eff_tps column, with the
-            # scalar path's spec-f64 baseline on the eff==0 fallback
-            ub = np.unique(gids[js])
-            uslots = np.asarray(g_row, np.int64)[ub]
-            rates = pool.store.col["eff_tps"][uslots].copy()
-            for t in np.flatnonzero(rates == 0.0).tolist():
-                rates[t] = pool.entitlements[
-                    g_ent[int(ub[t])]].baseline.tokens_per_second
-            ledger.ensure_rows(uslots, rates, now)
-            charge_ids = rids if js.size == m else \
-                [rids[t] for t in js.tolist()]
-            ok, ch_slots = ledger.charge_rows(
-                charge_ids, rows64[js], toks64[js], inps[js], mts[js],
-                now)
-            charged[js] = ok
+        with child("gateway.charge", pool_name):
+            ledger = pool.ledger
+            js = np.flatnonzero(admitted)
+            charged = np.zeros(m, bool)
+            ch_slots = np.empty(0, np.int64)
+            charge_ids: list[str] = []
+            if js.size:
+                # buckets ensured once per group with kernel admits (the
+                # same entitlement set the generic pass-1 loop ensures),
+                # vectorized: rates come off the eff_tps column, with the
+                # scalar path's spec-f64 baseline on the eff==0 fallback
+                ub = np.unique(gids[js])
+                uslots = np.asarray(g_row, np.int64)[ub]
+                rates = pool.store.col["eff_tps"][uslots].copy()
+                for t in np.flatnonzero(rates == 0.0).tolist():
+                    rates[t] = pool.entitlements[
+                        g_ent[int(ub[t])]].baseline.tokens_per_second
+                ledger.ensure_rows(uslots, rates, now)
+                charge_ids = rids if js.size == m else \
+                    [rids[t] for t in js.tolist()]
+                ok, ch_slots = ledger.charge_rows(
+                    charge_ids, rows64[js], toks64[js], inps[js], mts[js],
+                    now)
+                charged[js] = ok
 
-        acc = np.flatnonzero(charged)
-        w_l = req_w.tolist()
-        gid_l = gids.tolist()
-        if acc.size:
-            admit_ids = charge_ids if acc.size == js.size else \
-                [rids[t] for t in acc.tolist()]
-            pool.admit_rows(admit_ids, rows64[acc], kvs64[acc],
-                            toks64[acc], now, slots=ch_slots)
-            # demand lands exactly like the scalar register_admit
-            # loop: one unbuffered index-ordered f64 add chain
-            np.add.at(pool.store.col["demand_window"], rows64[acc],
-                      toks64[acc])
-            per_gid = np.bincount(gids[acc], minlength=len(g_ent))
-            for gid, cnt in enumerate(per_gid.tolist()):
-                if cnt:
-                    k_adm = f"admits:{g_ent[gid]}"
-                    incr_acc[k_adm] = incr_acc.get(k_adm, 0.0) \
-                        + float(cnt)
-                    if g_hop[gid] > 0:
-                        k_sp = f"spills:{g_key[gid]}"
-                        incr_acc[k_sp] = incr_acc.get(k_sp, 0.0) \
+            acc = np.flatnonzero(charged)
+            w_l = req_w.tolist()
+            gid_l = gids.tolist()
+            if acc.size:
+                admit_ids = charge_ids if acc.size == js.size else \
+                    [rids[t] for t in acc.tolist()]
+                pool.admit_rows(admit_ids, rows64[acc], kvs64[acc],
+                                toks64[acc], now, slots=ch_slots)
+                # demand lands exactly like the scalar register_admit
+                # loop: one unbuffered index-ordered f64 add chain
+                np.add.at(pool.store.col["demand_window"], rows64[acc],
+                          toks64[acc])
+                per_gid = np.bincount(gids[acc], minlength=len(g_ent))
+                for gid, cnt in enumerate(per_gid.tolist()):
+                    if cnt:
+                        k_adm = f"admits:{g_ent[gid]}"
+                        incr_acc[k_adm] = incr_acc.get(k_adm, 0.0) \
                             + float(cnt)
-            if acc.size == m:
-                it = zip(idx_l, rids, w_l, gid_l)
-            else:
-                it = ((idx_l[k], rids[k], w_l[k], gid_l[k])
-                      for k in acc.tolist())
-            # tuple.__new__ skips the NamedTuple default-filling
-            # wrapper — measurably faster at 10^5 responses/quantum
-            mk = tuple.__new__
-            for i, rid, w, gid in it:
-                responses[i] = mk(GatewayResponse,
-                                  (200, rid, None, None, w, pool_name,
-                                   g_ent[gid], g_hop[gid]))
+                        if g_hop[gid] > 0:
+                            k_sp = f"spills:{g_key[gid]}"
+                            incr_acc[k_sp] = incr_acc.get(k_sp, 0.0) \
+                                + float(cnt)
+                if acc.size == m:
+                    it = zip(idx_l, rids, w_l, gid_l)
+                else:
+                    it = ((idx_l[k], rids[k], w_l[k], gid_l[k])
+                          for k in acc.tolist())
+                # tuple.__new__ skips the NamedTuple default-filling
+                # wrapper — measurably faster at 10^5 responses/quantum
+                mk = tuple.__new__
+                for i, rid, w, gid in it:
+                    responses[i] = mk(GatewayResponse,
+                                      (200, rid, None, None, w, pool_name,
+                                       g_ent[gid], g_hop[gid]))
 
         den = np.flatnonzero(~charged)
         if den.size:
-            hint_cache: dict = {}
-            deny_ents: list[str] = []
-            deny_demand = np.zeros(den.size, np.float64)
-            deny_lp = np.zeros(den.size, bool)
-            adm_kernel = admitted.tolist()
-            reasons_l = reasons.tolist()
-            toks_l = toks64.tolist()
-            dcount: dict[str, int] = {}
-            for d, k in enumerate(den.tolist()):
-                ent = g_ent[gid_l[k]]
-                w = w_l[k]
-                code = 3 if adm_kernel[k] else int(reasons_l[k])
-                reason = _REASON_CODES[code]
-                retry = self._deny_hint(pool, pool_name, ent, reason,
-                                        toks_l[k], w, now,
-                                        cache=hint_cache)
-                deny_ents.append(ent)
-                if reason is not DenyReason.NOT_BOUND:
-                    deny_demand[d] = toks_l[k]
-                lp = reason is DenyReason.LOW_PRIORITY
-                deny_lp[d] = lp
-                dcount[ent] = dcount.get(ent, 0) + 1
-                responses[idx_l[k]] = GatewayResponse(
-                    status=429, request_id=rids[k],
-                    retry_after_s=retry, reason=reason.value,
-                    priority=w if lp else 0.0)
-            pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
-            for ent, cnt in dcount.items():
-                k_den = f"denials:{ent}"
-                incr_acc[k_den] = incr_acc.get(k_den, 0.0) + float(cnt)
-        if incr_acc:
-            store.incr_many(incr_acc, now)
-        if tel is not None:
-            # ONE flight scatter for the kernel batch, with reasons
-            # finalized the way responses were: a kernel admit the
-            # ledger rejected flips to TOKEN_BUDGET (code 3)
-            final_reasons = np.where(
-                charged, 0,
-                np.where(admitted, 3, reasons.astype(np.int64)))
-            tel.record_decisions(
-                pool_name, now, rids, rows64,
-                np.asarray(g_hop, np.int64)[gids], charged,
-                final_reasons.astype(np.int16),
-                np.asarray(req_w, np.float64),
-                float(snap.running_min_priority)
-                * (1.0 - pool.spec.admission_slack),
-                toks64,
-                levels_at=np.asarray(snap.bucket_level, np.float64))
+            with child("gateway.deny", pool_name):
+                hint_cache: dict = {}
+                deny_ents: list[str] = []
+                deny_demand = np.zeros(den.size, np.float64)
+                deny_lp = np.zeros(den.size, bool)
+                adm_kernel = admitted.tolist()
+                reasons_l = reasons.tolist()
+                toks_l = toks64.tolist()
+                dcount: dict[str, int] = {}
+                for d, k in enumerate(den.tolist()):
+                    ent = g_ent[gid_l[k]]
+                    w = w_l[k]
+                    code = 3 if adm_kernel[k] else int(reasons_l[k])
+                    reason = _REASON_CODES[code]
+                    retry = self._deny_hint(pool, pool_name, ent, reason,
+                                            toks_l[k], w, now,
+                                            cache=hint_cache)
+                    deny_ents.append(ent)
+                    if reason is not DenyReason.NOT_BOUND:
+                        deny_demand[d] = toks_l[k]
+                    lp = reason is DenyReason.LOW_PRIORITY
+                    deny_lp[d] = lp
+                    dcount[ent] = dcount.get(ent, 0) + 1
+                    responses[idx_l[k]] = GatewayResponse(
+                        status=429, request_id=rids[k],
+                        retry_after_s=retry, reason=reason.value,
+                        priority=w if lp else 0.0)
+                pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
+                for ent, cnt in dcount.items():
+                    k_den = f"denials:{ent}"
+                    incr_acc[k_den] = incr_acc.get(k_den, 0.0) + float(cnt)
+        with child("gateway.record", pool_name):
+            if incr_acc:
+                store.incr_many(incr_acc, now)
+            if tel is not None:
+                # ONE flight scatter for the kernel batch, with reasons
+                # finalized the way responses were: a kernel admit the
+                # ledger rejected flips to TOKEN_BUDGET (code 3)
+                final_reasons = np.where(
+                    charged, 0,
+                    np.where(admitted, 3, reasons.astype(np.int64)))
+                tel.record_decisions(
+                    pool_name, now, rids, rows64,
+                    np.asarray(g_hop, np.int64)[gids], charged,
+                    final_reasons.astype(np.int16),
+                    np.asarray(req_w, np.float64),
+                    float(snap.running_min_priority)
+                    * (1.0 - pool.spec.admission_slack),
+                    toks64,
+                    levels_at=np.asarray(snap.bucket_level, np.float64))
 
     @hot_path
     def _admit_batch(self, pool_name: str, batch: list[_Pending],
@@ -816,15 +848,16 @@ class Gateway:
                        * p.req.kv_bytes_per_token)
             eff_max.append(mt)
         if tel is not None and nb_rids:
-            tel.record_decisions(
-                pool_name, now, nb_rids,
-                np.full(len(nb_rids), -1, np.int64),
-                np.asarray(nb_hops, np.int64),
-                np.zeros(len(nb_rids), bool),
-                np.full(len(nb_rids), 1, np.int16),   # NOT_BOUND
-                0.0, float(snap.running_min_priority)
-                * (1.0 - pool.spec.admission_slack),
-                np.asarray(nb_toks, np.float64))
+            with child("gateway.record", pool_name):
+                tel.record_decisions(
+                    pool_name, now, nb_rids,
+                    np.full(len(nb_rids), -1, np.int64),
+                    np.asarray(nb_hops, np.int64),
+                    np.zeros(len(nb_rids), bool),
+                    np.full(len(nb_rids), 1, np.int16),   # NOT_BOUND
+                    0.0, float(snap.running_min_priority)
+                    * (1.0 - pool.spec.admission_slack),
+                    np.asarray(nb_toks, np.float64))
         if not kernel_batch:
             return spilled
 
@@ -832,99 +865,100 @@ class Gateway:
         admitted, reasons, req_w = self._dispatch_admit(
             pool, snap, rows, tokens, kvs, m)
 
-        # -- scatter, pass 1: the quantum's charges, in replay order —
-        # array-native: no per-request ``Charge`` objects, accepted
-        # charges land as batched request-table column writes
-        # (``Ledger.charge_rows``).  Buckets are ensured once per
-        # entitlement; the ledger re-checks every charge (it stays
-        # authoritative if f32/f64 disagree on an exact budget
-        # boundary — those flip to budget denials below).
-        ledger = pool.ledger
-        slot_of = pool.store.slot_of
-        ensured: set = set()
-        charge_js: list[int] = []
-        charge_ids: list[str] = []
-        ent_slots: list[int] = []
-        inp_toks: list[int] = []
-        max_toks: list[int] = []
-        for j, p in enumerate(kernel_batch):
-            if not admitted[j]:
-                continue
-            ent = p.current()[1].entitlement
-            if ent not in ensured:
-                st = pool.status[ent]
-                ledger.ensure(
-                    ent, st.effective.tokens_per_second
-                    or pool.entitlements[ent].baseline.tokens_per_second,
-                    now)
-                ensured.add(ent)
-            charge_js.append(j)
-            charge_ids.append(p.req.request_id)
-            ent_slots.append(slot_of[ent])
-            inp_toks.append(p.req.input_tokens)
-            max_toks.append(int(eff_max[j]))
-        tokens64 = np.asarray(tokens, np.float64)
-        kvs64 = np.asarray(kvs, np.float64)
-        charged = np.zeros(m, bool)
-        js = np.asarray(charge_js, np.int64)
-        owners = np.asarray(ent_slots, np.int64)
-        ch_slots = np.empty(0, np.int64)
-        if charge_js:
-            ok, ch_slots = ledger.charge_rows(
-                charge_ids, owners, tokens64[js],
-                np.asarray(inp_toks, np.int64),
-                np.asarray(max_toks, np.int64), now)
-            charged[js] = ok
+        incr_acc: dict[str, float] = {}
+        with child("gateway.charge", pool_name):
+            # -- scatter, pass 1: the quantum's charges, in replay order —
+            # array-native: no per-request ``Charge`` objects, accepted
+            # charges land as batched request-table column writes
+            # (``Ledger.charge_rows``).  Buckets are ensured once per
+            # entitlement; the ledger re-checks every charge (it stays
+            # authoritative if f32/f64 disagree on an exact budget
+            # boundary — those flip to budget denials below).
+            ledger = pool.ledger
+            slot_of = pool.store.slot_of
+            ensured: set = set()
+            charge_js: list[int] = []
+            charge_ids: list[str] = []
+            ent_slots: list[int] = []
+            inp_toks: list[int] = []
+            max_toks: list[int] = []
+            for j, p in enumerate(kernel_batch):
+                if not admitted[j]:
+                    continue
+                ent = p.current()[1].entitlement
+                if ent not in ensured:
+                    st = pool.status[ent]
+                    ledger.ensure(
+                        ent, st.effective.tokens_per_second
+                        or pool.entitlements[ent].baseline.tokens_per_second,
+                        now)
+                    ensured.add(ent)
+                charge_js.append(j)
+                charge_ids.append(p.req.request_id)
+                ent_slots.append(slot_of[ent])
+                inp_toks.append(p.req.input_tokens)
+                max_toks.append(int(eff_max[j]))
+            tokens64 = np.asarray(tokens, np.float64)
+            kvs64 = np.asarray(kvs, np.float64)
+            charged = np.zeros(m, bool)
+            js = np.asarray(charge_js, np.int64)
+            owners = np.asarray(ent_slots, np.int64)
+            ch_slots = np.empty(0, np.int64)
+            if charge_js:
+                ok, ch_slots = ledger.charge_rows(
+                    charge_ids, owners, tokens64[js],
+                    np.asarray(inp_toks, np.int64),
+                    np.asarray(max_toks, np.int64), now)
+                charged[js] = ok
 
-        # -- scatter, pass 2a: admits.  ONE ``admit_rows`` column
-        # scatter — no per-request ``InFlight`` objects — and counter
-        # increments are aggregated: the StateStore and store columns
-        # are hit once per distinct key per quantum, not per request.
-        acc = np.flatnonzero(charged[js]) if charge_js else js
-        if acc.size:
-            n_admits: dict = {}
-            n_spills: dict = {}
-            demand: dict = {}
-            # (row slot index in this admit batch, preferred leg) for
-            # requests served off a spill leg — tagged on the new rows
-            # below for completion-time debt transfer
-            spill_tags: list[tuple[int, tuple[str, str]]] = []
-            acc_l = acc.tolist()
-            for k, i in enumerate(acc_l):
-                p = kernel_batch[charge_js[i]]
-                hop, leg = p.current()
-                ent = leg.entitlement
-                w = float(req_w[charge_js[i]])
-                demand[ent] = demand.get(ent, 0.0) \
-                    + float(tokens[charge_js[i]])
-                n_admits[ent] = n_admits.get(ent, 0) + 1
-                if hop > 0:
-                    key = p.req.api_key
-                    n_spills[key] = n_spills.get(key, 0) + 1
-                if p.leg_ptr > 0:
-                    first = p.legs[0][1]
-                    spill_tags.append((k, (first.pool,
-                                           first.entitlement)))
-                responses[p.idx] = GatewayResponse(
-                    status=200, request_id=p.req.request_id,
-                    priority=w, pool=pool_name, entitlement=ent,
-                    spill_hops=hop)
-            js_acc = js[acc]
-            # ch_slots aligns with the accepted subset of the charge
-            # batch in charge order — exactly this admit batch, so the
-            # rows charged are the rows admitted (no second id lookup)
-            slots = pool.admit_rows(
-                [charge_ids[i] for i in acc_l], owners[acc],
-                kvs64[js_acc], tokens64[js_acc], now,
-                demand_tokens=demand, slots=ch_slots)
-            spill_col = pool.table.spill_from
-            for k, leg_from in spill_tags:
-                spill_col[int(slots[k])] = leg_from
-            incr_acc = {f"admits:{ent}": float(cnt)
-                        for ent, cnt in n_admits.items()}
-            for key, cnt in n_spills.items():
-                incr_acc[f"spills:{key}"] = float(cnt)
-            self.store.incr_many(incr_acc, now)
+            # -- scatter, pass 2a: admits.  ONE ``admit_rows`` column
+            # scatter — no per-request ``InFlight`` objects — and counter
+            # increments are aggregated: the StateStore and store columns
+            # are hit once per distinct key per quantum, not per request.
+            acc = np.flatnonzero(charged[js]) if charge_js else js
+            if acc.size:
+                n_admits: dict = {}
+                n_spills: dict = {}
+                demand: dict = {}
+                # (row slot index in this admit batch, preferred leg) for
+                # requests served off a spill leg — tagged on the new rows
+                # below for completion-time debt transfer
+                spill_tags: list[tuple[int, tuple[str, str]]] = []
+                acc_l = acc.tolist()
+                for k, i in enumerate(acc_l):
+                    p = kernel_batch[charge_js[i]]
+                    hop, leg = p.current()
+                    ent = leg.entitlement
+                    w = float(req_w[charge_js[i]])
+                    demand[ent] = demand.get(ent, 0.0) \
+                        + float(tokens[charge_js[i]])
+                    n_admits[ent] = n_admits.get(ent, 0) + 1
+                    if hop > 0:
+                        key = p.req.api_key
+                        n_spills[key] = n_spills.get(key, 0) + 1
+                    if p.leg_ptr > 0:
+                        first = p.legs[0][1]
+                        spill_tags.append((k, (first.pool,
+                                               first.entitlement)))
+                    responses[p.idx] = GatewayResponse(
+                        status=200, request_id=p.req.request_id,
+                        priority=w, pool=pool_name, entitlement=ent,
+                        spill_hops=hop)
+                js_acc = js[acc]
+                # ch_slots aligns with the accepted subset of the charge
+                # batch in charge order — exactly this admit batch, so the
+                # rows charged are the rows admitted (no second id lookup)
+                slots = pool.admit_rows(
+                    [charge_ids[i] for i in acc_l], owners[acc],
+                    kvs64[js_acc], tokens64[js_acc], now,
+                    demand_tokens=demand, slots=ch_slots)
+                spill_col = pool.table.spill_from
+                for k, leg_from in spill_tags:
+                    spill_col[int(slots[k])] = leg_from
+                incr_acc = {f"admits:{ent}": float(cnt)
+                            for ent, cnt in n_admits.items()}
+                for key, cnt in n_spills.items():
+                    incr_acc[f"spills:{key}"] = float(cnt)
 
         # -- scatter, pass 2b: denials.  Runs AFTER the quantum's
         # admits are registered, so Retry-After hints reflect the pool
@@ -938,43 +972,47 @@ class Gateway:
         # is evaluated at most once per batch.
         deny_js = np.flatnonzero(~charged)
         if deny_js.size:
-            hint_cache: dict = {}
-            deny_ents: list[str] = []
-            deny_demand = np.zeros(deny_js.size, np.float64)
-            deny_lp = np.zeros(deny_js.size, bool)
-            for k, j in enumerate(deny_js.tolist()):
-                p = kernel_batch[j]
-                ent = p.current()[1].entitlement
-                w = float(req_w[j])
-                code = 3 if admitted[j] else int(reasons[j])
-                reason = _REASON_CODES[code]
-                retry = self._deny_hint(pool, pool_name, ent, reason,
-                                        float(tokens[j]), w, now,
-                                        cache=hint_cache)
-                deny_ents.append(ent)
-                if reason is not DenyReason.NOT_BOUND:
-                    deny_demand[k] = float(tokens[j])
-                deny_lp[k] = reason is DenyReason.LOW_PRIORITY
-                p.note_denial(reason,
-                              w if reason is DenyReason.LOW_PRIORITY
-                              else 0.0, retry)
-                p.leg_ptr += 1
-                spilled.append(p)
-            pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
-        if tel is not None:
-            final_reasons = np.where(
-                charged, 0,
-                np.where(admitted, 3, reasons.astype(np.int64)))
-            tel.record_decisions(
-                pool_name, now,
-                [p.req.request_id for p in kernel_batch],
-                np.asarray(rows, np.int64), np.asarray(hops, np.int64),
-                charged, final_reasons.astype(np.int16),
-                np.asarray(req_w, np.float64),
-                float(snap.running_min_priority)
-                * (1.0 - pool.spec.admission_slack),
-                tokens64,
-                levels_at=np.asarray(snap.bucket_level, np.float64))
+            with child("gateway.deny", pool_name):
+                hint_cache: dict = {}
+                deny_ents: list[str] = []
+                deny_demand = np.zeros(deny_js.size, np.float64)
+                deny_lp = np.zeros(deny_js.size, bool)
+                for k, j in enumerate(deny_js.tolist()):
+                    p = kernel_batch[j]
+                    ent = p.current()[1].entitlement
+                    w = float(req_w[j])
+                    code = 3 if admitted[j] else int(reasons[j])
+                    reason = _REASON_CODES[code]
+                    retry = self._deny_hint(pool, pool_name, ent, reason,
+                                            float(tokens[j]), w, now,
+                                            cache=hint_cache)
+                    deny_ents.append(ent)
+                    if reason is not DenyReason.NOT_BOUND:
+                        deny_demand[k] = float(tokens[j])
+                    deny_lp[k] = reason is DenyReason.LOW_PRIORITY
+                    p.note_denial(reason,
+                                  w if reason is DenyReason.LOW_PRIORITY
+                                  else 0.0, retry)
+                    p.leg_ptr += 1
+                    spilled.append(p)
+                pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
+        with child("gateway.record", pool_name):
+            if incr_acc:
+                self.store.incr_many(incr_acc, now)
+            if tel is not None:
+                final_reasons = np.where(
+                    charged, 0,
+                    np.where(admitted, 3, reasons.astype(np.int64)))
+                tel.record_decisions(
+                    pool_name, now,
+                    [p.req.request_id for p in kernel_batch],
+                    np.asarray(rows, np.int64), np.asarray(hops, np.int64),
+                    charged, final_reasons.astype(np.int16),
+                    np.asarray(req_w, np.float64),
+                    float(snap.running_min_priority)
+                    * (1.0 - pool.spec.admission_slack),
+                    tokens64,
+                    levels_at=np.asarray(snap.bucket_level, np.float64))
         return spilled
 
     def _deny_hint(self, pool: TokenPool, pool_name: str, ent: str,
@@ -1027,12 +1065,12 @@ class Gateway:
         """Run one fleet planning round (``PoolManager.plan_quantum``)
         and surface it in the gateway's stats store: per-pool replica
         gauges, scale-up/down counters, and migration counters —
-        the same observability surface the admission counters use."""
-        t0 = time.perf_counter()
-        plan = self.manager.plan_quantum(now, records=records)
+        the same observability surface the admission counters use.
+        The round is the ``fleet.plan`` span."""
+        with span(self.telemetry, "fleet.plan", now=now):
+            plan = self.manager.plan_quantum(now, records=records)
         if self.telemetry is not None:
-            self.telemetry.on_plan(now, plan,
-                                   time.perf_counter() - t0)
+            self.telemetry.on_plan(now, plan)
         for name, d in plan.decisions.items():
             self.store.set(f"replicas:{name}", float(d.desired), now)
         # count authorization TRANSITIONS, not convergence rounds —

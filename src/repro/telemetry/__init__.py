@@ -1,6 +1,6 @@
 """Vectorized telemetry plane: metrics registry, admission flight
-recorder, SLO attainment tracking, and Prometheus / JSON /
-Chrome-trace exporters.
+recorder, SLO attainment tracking, program spans, and Prometheus / JSON
+/ Chrome-trace exporters.
 
 Quickstart::
 
@@ -10,6 +10,37 @@ Quickstart::
     print(gw.telemetry.prometheus())         # Prometheus exposition
     print(gw.telemetry.flight.explain(rid).narrative())
     open("trace.json", "w").write(gw.telemetry.chrome_trace())
+    gw.telemetry.spans.rows()                # the spans, as arrays
+
+Spans (``repro.telemetry.spans``) are on whenever a ``Telemetry`` is
+attached, and their names are :data:`SPAN_NAMES`, nested as the calls
+nest::
+
+    gateway.quantum   handle_quantum (root)
+      gateway.route     key -> legs: store reads, route_order_indexed
+      gateway.snapshot  quantum_snapshot: uploads, priority_batch and
+                        the owner gather up to its readback
+      gateway.admit     padding, upload, admit_quantum, readback
+      gateway.charge    ledger charges, admit_rows, demand, the 200s
+      gateway.deny      Retry-After hints, register_deny_batch, the 429s
+      gateway.record    flight rows, decision counters, store incr_many
+    pool.tick         TokenPool.tick or one control_tick_pools group (root)
+      pool.measure      window fold and the kernel-input uploads
+      pool.kernel       dispatch through the alloc/weights readback
+      pool.absorb       the kernel's state adopted, buckets re-rated
+    fleet.plan        Gateway.plan_quantum (root)
+      fleet.kernel      plan_fleet from upload through readback
+      fleet.rebalance   _rebalance with _starvation
+    compile           a backend compile, under the span that caused it
+
+Every span is timed on one clock (``time.perf_counter``), carries its
+parent and the id of its root call, and enters a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler trace holds
+it beside the device's operations.  As a root closes its spans fold
+into the histogram ``repro_span_duration_seconds{span,pool}`` and the
+bytes they moved into ``repro_transfer_bytes_total{direction,span}``
+(``h2d`` / ``d2h``).  The Chrome timeline is drawn from the same table
+on the same clock; a simulator's ``now`` is kept in its args.
 """
 from repro.telemetry.export import (TraceBuffer, chrome_trace_json,
                                     json_snapshot, prometheus_text)
@@ -19,6 +50,7 @@ from repro.telemetry.flight import (DecisionTrace, FlightRecorder,
 from repro.telemetry.registry import (Counter, Gauge, Histogram,
                                       MetricsRegistry)
 from repro.telemetry.slo import SloTracker, TIER_NAMES
+from repro.telemetry.spans import SPAN_NAMES, SpanTable
 
 __all__ = [
     "Counter",
@@ -28,7 +60,9 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "SPAN_NAMES",
     "SloTracker",
+    "SpanTable",
     "TIER_NAMES",
     "Telemetry",
     "TraceBuffer",

@@ -1,11 +1,12 @@
 """Exporters: Prometheus text exposition, JSON snapshot, and a
 Chrome-trace-event (Perfetto-loadable) timeline.
 
-All three are COLD paths — they read registry arrays / the trace
-buffer, never the other way round.  The trace buffer itself is
-append-only Python (events are rare relative to decisions: one per
-quantum / tick / scale event / incident, not one per request), with a
-hard cap so a long simulation cannot grow without bound.
+All three are COLD paths — they read registry arrays, the span table
+and the trace buffer, never the other way round.  The timeline's
+slices are the program's spans (a bounded table, read out at export);
+its markers are append-only Python under a hard cap (they are rare:
+one per scale event, migration, tick or incident, never one per
+request).
 
 Chrome trace format notes (``chrome://tracing`` / ui.perfetto.dev):
 timestamps and durations are MICROseconds; ``ph`` codes used here are
@@ -15,10 +16,12 @@ timestamps and durations are MICROseconds; ``ph`` codes used here are
 from __future__ import annotations
 
 import json
+import time
 from typing import Optional
 
 from repro.telemetry.registry import (Counter, Gauge, Histogram,
                                       MetricsRegistry)
+from repro.telemetry.spans import SpanTable
 
 __all__ = ["TraceBuffer", "chrome_trace_json", "json_snapshot",
            "prometheus_text"]
@@ -109,58 +112,94 @@ def json_snapshot(registry: MetricsRegistry) -> dict:
 # ---------------------------------------------------------------------------
 
 class TraceBuffer:
-    """Append-only Chrome-trace event list with a hard cap.  Tracks
-    (``tid``) are interned per pool/source; ``pid`` is always 1 (one
-    logical process — the control plane)."""
+    """The Chrome timeline of one ``Telemetry``: every program span of
+    its :class:`~repro.telemetry.spans.SpanTable` as a complete slice,
+    plus rare markers (scale and migration instants, water-fill
+    counters, incident windows) under a hard cap.  One clock for all:
+    ``ts`` is microseconds of ``time.perf_counter`` since the table's
+    ``t0``; a caller's own clock (a simulator's ``now``) is kept in
+    ``args``, never as ``ts``.  ``pid`` is always 1 (one logical
+    process, the control plane); spans share one track, on which they
+    nest as they ran."""
 
-    def __init__(self, max_events: int = 200_000) -> None:
-        self.events: list[dict] = []
+    SPAN_TRACK = "control plane"
+
+    def __init__(self, spans: SpanTable, max_events: int = 200_000) -> None:
+        self.spans = spans
+        self.markers: list[dict] = []
         self.max_events = max_events
         self.dropped = 0
         self._tids: dict[str, int] = {}
 
     def tid(self, track: str) -> int:
-        """Intern a track name → tid (emits the ``M`` metadata event
-        naming the track on first use)."""
+        """Intern a track name → tid."""
         t = self._tids.get(track)
         if t is None:
-            t = len(self._tids) + 1
-            self._tids[track] = t
-            self.events.append({
-                "name": "thread_name", "ph": "M", "pid": 1, "tid": t,
-                "args": {"name": track}})
+            t = self._tids[track] = len(self._tids) + 1
         return t
 
+    def _us(self, t: float) -> float:
+        return (t - self.spans.t0) * 1e6
+
     def _push(self, ev: dict) -> None:
-        if len(self.events) >= self.max_events:
+        if len(self.markers) >= self.max_events:
             self.dropped += 1
             return
-        self.events.append(ev)
+        self.markers.append(ev)
 
-    def complete(self, name: str, track: str, ts_s: float,
-                 dur_s: float, args: Optional[dict] = None) -> None:
-        """A ``ph:X`` slice — quanta, ticks, incident windows."""
+    def complete(self, name: str, track: str, start: float, end: float,
+                 args: Optional[dict] = None) -> None:
+        """A ``ph:X`` slice from ``start`` to ``end`` (``perf_counter``
+        seconds): incident windows."""
         self._push({"name": name, "ph": "X", "pid": 1,
-                    "tid": self.tid(track),
-                    "ts": ts_s * 1e6, "dur": max(0.0, dur_s) * 1e6,
+                    "tid": self.tid(track), "ts": self._us(start),
+                    "dur": max(0.0, end - start) * 1e6,
                     "args": args or {}})
 
-    def instant(self, name: str, track: str, ts_s: float,
+    def instant(self, name: str, track: str, now: float,
                 args: Optional[dict] = None) -> None:
-        """A ``ph:i`` marker — scale/migration events."""
+        """A ``ph:i`` marker at this moment: scale/migration events."""
         self._push({"name": name, "ph": "i", "s": "t", "pid": 1,
-                    "tid": self.tid(track), "ts": ts_s * 1e6,
-                    "args": args or {}})
+                    "tid": self.tid(track),
+                    "ts": self._us(time.perf_counter()),
+                    "args": {"now": now, **(args or {})}})
 
-    def counter(self, name: str, track: str, ts_s: float,
-                values: dict) -> None:
-        """A ``ph:C`` sample — water-fill level / debt timelines."""
+    def counter(self, name: str, track: str, values: dict) -> None:
+        """A ``ph:C`` sample at this moment: water-fill level / debt."""
         self._push({"name": name, "ph": "C", "pid": 1,
-                    "tid": self.tid(track), "ts": ts_s * 1e6,
-                    "args": values})
+                    "tid": self.tid(track),
+                    "ts": self._us(time.perf_counter()), "args": values})
+
+    def events(self) -> list[dict]:
+        """Track names, the spans, then the markers."""
+        rows = self.spans.rows()
+        tid = self.tid(self.SPAN_TRACK)
+        slices = []
+        for i in range(rows["id"].size):
+            args = {"id": int(rows["id"][i]),
+                    "parent": int(rows["parent"][i]),
+                    "root": int(rows["root"][i])}
+            if rows["pool"][i]:
+                args["pool"] = rows["pool"][i]
+            now = float(rows["now"][i])
+            if now == now:
+                args["now"] = now
+            for col in ("h2d", "d2h"):
+                if rows[col][i]:
+                    args[f"{col}_bytes"] = int(rows[col][i])
+            if rows["cache_hit"][i] >= 0:
+                args["cache_hit"] = bool(rows["cache_hit"][i])
+            start, end = float(rows["start"][i]), float(rows["end"][i])
+            slices.append({"name": rows["name"][i], "ph": "X", "pid": 1,
+                           "tid": tid, "ts": self._us(start),
+                           "dur": (end - start) * 1e6, "args": args})
+        meta = [{"name": "thread_name", "ph": "M", "pid": 1, "tid": t,
+                 "args": {"name": track}}
+                for track, t in self._tids.items()]
+        return meta + slices + self.markers
 
 
 def chrome_trace_json(trace: TraceBuffer) -> str:
     """Serialize to the JSON object form Perfetto loads directly."""
-    return json.dumps({"traceEvents": trace.events,
+    return json.dumps({"traceEvents": trace.events(),
                        "displayTimeUnit": "ms"})

@@ -1,6 +1,7 @@
 """The ``Telemetry`` facade — one object wiring the registry, the
-admission flight recorder, the SLO tracker and the trace buffer into
-the gateway / pool / simulator instrumentation points.
+admission flight recorder, the SLO tracker, the program's span table
+and the trace timeline drawn from it into the gateway / pool /
+simulator instrumentation points.
 
 Recording discipline matches the rest of the control plane:
 
@@ -11,6 +12,11 @@ Recording discipline matches the rest of the control plane:
 * per-EVENT surfaces (``on_tick``, ``on_quantum``, ``on_plan``,
   incidents) fire once per tick/quantum/plan — O(pools) per tick, not
   O(requests) — so they may use the scalar recorders;
+* spans (``repro.telemetry.spans``) time the quantum, tick and plan and
+  their parts; as each root span closes, its spans fold into
+  ``repro_span_duration_seconds{span,pool}`` and the bytes they moved
+  into ``repro_transfer_bytes_total{direction,span}`` — one batch
+  row-op each per root, O(spans);
 * the scalar ``record_decision`` twin serves the sequential
   ``Gateway.handle`` path and doubles as the flight-recorder parity
   oracle.
@@ -35,6 +41,7 @@ from repro.telemetry.export import (TraceBuffer, chrome_trace_json,
 from repro.telemetry.flight import FlightRecorder
 from repro.telemetry.registry import MetricsRegistry
 from repro.telemetry.slo import TIER_NAMES, SloTracker
+from repro.telemetry.spans import SPAN_CAPACITY, SPAN_NAMES, SpanTable
 
 __all__ = ["Telemetry"]
 
@@ -42,14 +49,15 @@ _N_TIERS = len(TIER_NAMES)
 
 
 class Telemetry:
-    """Registry + flight recorder + SLO tracker + trace timeline."""
+    """Registry + flight recorder + SLO tracker + spans + timeline."""
 
     def __init__(self, flight_capacity: int = 65536,
                  trace_max_events: int = 200_000) -> None:
         self.registry = MetricsRegistry()
         self.flight = FlightRecorder(flight_capacity)
         self.slo = SloTracker(self.registry)
-        self.trace = TraceBuffer(trace_max_events)
+        self.spans = SpanTable(SPAN_CAPACITY, on_root=self._fold_root)
+        self.trace = TraceBuffer(self.spans, trace_max_events)
 
         r = self.registry
         self.decisions = r.counter(
@@ -60,14 +68,15 @@ class Telemetry:
             "repro_gateway_terminal_total",
             help="Requests that never reached a pool decision.",
             labels=("verdict",))
-        self.tick_duration = r.histogram(
-            "repro_pool_tick_duration_seconds",
-            help="Wall-clock duration of one control tick.",
-            labels=("pool",), lo=1e-6, hi=10.0, buckets=40)
-        self.quantum_duration = r.histogram(
-            "repro_gateway_quantum_duration_seconds",
-            help="Wall-clock duration of one admission quantum.",
-            lo=1e-6, hi=10.0, buckets=40)
+        self.span_duration = r.histogram(
+            "repro_span_duration_seconds",
+            help="Wall-clock duration of each program span.",
+            labels=("span", "pool"), lo=1e-6, hi=10.0, buckets=40)
+        self.transfer_bytes = r.counter(
+            "repro_transfer_bytes_total",
+            help="Bytes moved between host and device, by the span "
+                 "that moved them.",
+            labels=("direction", "span"))
         self.quantum_requests = r.counter(
             "repro_gateway_quantum_requests_total",
             help="Requests processed through handle_quantum.")
@@ -94,7 +103,6 @@ class Telemetry:
             "repro_incidents_total",
             help="Incident windows opened (failures, chaos events).")
 
-        self._q_sid = self.quantum_duration.series(())
         self._qreq_sid = self.quantum_requests.series(())
         self._migr_sid = self.migrations.series(())
         self._incid_sid = self.incidents.series(())
@@ -107,12 +115,14 @@ class Telemetry:
         self._pools: dict = {}
         #: pool name → (2, n_tiers) decision sids [admit/deny, tier]
         self._dec_sids: dict[str, np.ndarray] = {}
-        #: pool name → (tick-histogram sid, waterfill sid, debt sid)
-        self._tick_sids: dict[str, tuple[int, int, int]] = {}
+        #: pool name → (waterfill sid, debt sid)
+        self._tick_sids: dict[str, tuple[int, int]] = {}
         #: (pool, entitlement) → (class code, slo seconds)
         self._tier_cache: dict[tuple, tuple[int, float]] = {}
-        #: open incident windows: key → start clock
+        #: open incident windows: key → start clock (and its start on
+        #: the timeline's clock)
         self._open_incidents: dict[str, float] = {}
+        self._incident_t: dict[str, float] = {}
         #: closed incident windows: (key, start, end) in close order
         self._closed_incidents: list[tuple[str, float, float]] = []
 
@@ -138,7 +148,6 @@ class Telemetry:
             sids[1, t] = self.decisions.series((name, tier, "deny"))
         self._dec_sids[name] = sids
         self._tick_sids[name] = (
-            self.tick_duration.series((name,)),
             self.waterfill.series((name,)),
             self.debt_total.series((name,)))
 
@@ -274,39 +283,54 @@ class Telemetry:
                 else "unroutable")
         self.terminal.inc(self._term_sids[name])
 
+    # -- spans ---------------------------------------------------------------
+    def _fold_root(self, root: int) -> None:
+        """A root span closed: fold it and every span inside it into
+        the duration histogram and the transfer counter (one batch
+        row-op each; series ids are dict lookups, O(spans))."""
+        t = self.spans
+        r = np.arange(root, t.next_id) & (t.capacity - 1)
+        names = [SPAN_NAMES[c] for c in t.name[r].tolist()]
+        pools = [t.pools[p] if p >= 0 else "" for p in t.pool[r].tolist()]
+        self.span_duration.observe_rows(
+            t.end[r] - t.start[r],
+            np.asarray([self.span_duration.series(key)
+                        for key in zip(names, pools)], np.int64))
+        for direction, col in (("h2d", t.h2d), ("d2h", t.d2h)):
+            moved = col[r]
+            hit = np.flatnonzero(moved)
+            if hit.size:
+                self.transfer_bytes.inc_rows(
+                    np.asarray([self.transfer_bytes.series(
+                        (direction, names[k])) for k in hit.tolist()],
+                        np.int64),
+                    moved[hit].astype(np.float64))
+
     # -- per-event surfaces (once per tick/quantum/plan) -------------------
-    def on_tick(self, pool_name: str, now: float, duration_s: float,
-                alloc_total: float, debt_total: float,
-                in_flight: int) -> None:
-        """One pool control tick: duration histogram, water-fill /
-        debt gauges, and a trace slice + counter track."""
+    def on_tick(self, pool_name: str, now: float, alloc_total: float,
+                debt_total: float, in_flight: int) -> None:
+        """One pool control tick: water-fill / debt gauges and their
+        counter track (the tick's duration is its ``pool.tick`` span)."""
         sids = self._tick_sids.get(pool_name)
         if sids is None:
             return
-        tick_sid, wf_sid, debt_sid = sids
-        self.tick_duration.observe(tick_sid, duration_s)
+        wf_sid, debt_sid = sids
         self.waterfill.set(wf_sid, alloc_total)
         self.debt_total.set(debt_sid, debt_total)
-        track = f"pool:{pool_name}"
-        self.trace.complete(
-            "control_tick", track, now, duration_s,
-            args={"alloc_tokens": alloc_total, "debt": debt_total,
-                  "in_flight": in_flight})
         self.trace.counter(
-            f"waterfill:{pool_name}", track, now,
-            {"tokens": alloc_total, "debt": debt_total})
+            f"waterfill:{pool_name}", f"pool:{pool_name}",
+            {"tokens": alloc_total, "debt": debt_total,
+             "in_flight": in_flight})
 
-    def on_quantum(self, now: float, n_requests: int,
-                   duration_s: float) -> None:
-        """One admission quantum through ``handle_quantum``."""
-        self.quantum_duration.observe(self._q_sid, duration_s)
+    def on_quantum(self, n_requests: int) -> None:
+        """One admission quantum through ``handle_quantum`` (its
+        duration is its ``gateway.quantum`` span)."""
         self.quantum_requests.inc(self._qreq_sid, float(n_requests))
-        self.trace.complete("admit_quantum", "gateway", now, duration_s,
-                            args={"requests": n_requests})
 
-    def on_plan(self, now: float, plan, duration_s: float) -> None:
+    def on_plan(self, now: float, plan) -> None:
         """One fleet planning round: replica gauges, scale/migration
-        counters, trace markers."""
+        counters, trace markers (its duration is its ``fleet.plan``
+        span)."""
         for name, d in plan.decisions.items():
             self.replicas.set(self.replicas.series((name,)),
                               float(d.desired))
@@ -324,10 +348,10 @@ class Telemetry:
             self.trace.instant(
                 f"migrate:{prop.entitlement}", "fleet", now,
                 args={"dst": prop.dst})
-        self.trace.complete("plan_quantum", "fleet", now, duration_s)
 
     def incident_start(self, key: str, now: float) -> None:
         self._open_incidents[key] = now
+        self._incident_t[key] = time.perf_counter()
         self.incidents.inc(self._incid_sid)
         self.trace.instant(f"incident_start:{key}", "incidents", now)
 
@@ -336,8 +360,9 @@ class Telemetry:
         if start is None:
             return
         self._closed_incidents.append((key, start, now))
-        self.trace.complete(f"incident:{key}", "incidents", start,
-                            now - start)
+        self.trace.complete(f"incident:{key}", "incidents",
+                            self._incident_t.pop(key), time.perf_counter(),
+                            args={"start": start, "end": now})
 
     def incident_windows(self) -> list[tuple[str, float, Optional[float]]]:
         """All incident windows as ``(key, start, end)`` — closed ones
@@ -358,13 +383,8 @@ class Telemetry:
             "metrics": json_snapshot(self.registry),
             "slo": self.slo.snapshot(),
             "flight_rows": len(self.flight),
-            "trace_events": len(self.trace.events),
+            "trace_events": len(self.trace.events()),
         }
 
     def chrome_trace(self) -> str:
         return chrome_trace_json(self.trace)
-
-    @staticmethod
-    def clock() -> float:
-        """Wall-clock source for duration measurements."""
-        return time.perf_counter()

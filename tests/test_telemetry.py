@@ -522,8 +522,8 @@ class TestExporters:
             if ev["ph"] == "X":
                 assert ev["dur"] >= 0.0
             names.add(ev["name"])
-        assert "control_tick" in names
-        assert "admit_quantum" in names
+        assert "pool.tick" in names
+        assert "gateway.quantum" in names
 
     def test_json_snapshot(self):
         tel = self._telemetry_with_traffic()
