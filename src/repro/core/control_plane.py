@@ -51,7 +51,7 @@ ELASTIC_MASK = jnp.array([False, False, True, False, False])
 #: bucket never retraces (``tests/test_resident.py``).
 TRACE_COUNTS: dict[str, int] = {"control_tick": 0, "admit_quantum": 0,
                                 "shard_tick": 0, "shard_admit_quantum": 0,
-                                "shard_plan_fleet": 0}
+                                "shard_plan_fleet": 0, "owner_min": 0}
 
 
 @jax.tree_util.register_dataclass

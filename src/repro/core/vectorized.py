@@ -13,7 +13,8 @@ execute it directly.  This module keeps:
   over a ``TokenPool``'s RESIDENT arrays (``core.resident``): the
   kernel state is the store's cached device mirror and bucket levels
   are one vectorized projection, with nothing mutated and nothing
-  gathered per row;
+  gathered per row; the running-min seed is :func:`owner_min`, one
+  fixed-width masked min over the in-flight owner rows;
 - aliases (``PoolArrays``, ``tick_batch``, ``waterfill_batch``, …) so
   existing imports keep working.
 """
@@ -25,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.control_plane import (
     BURSTOK_MASK as _BURSTOK,
@@ -35,6 +37,7 @@ from repro.core.control_plane import (
     ELASTIC_MASK as _ELASTIC,
     PROTECTED_MASK as _PROTECTED,
     allocate_rows as allocate_tps_batch,
+    bucket_width,
     burst_delta_rows as burst_delta_batch,
     control_tick,
     ewma,
@@ -43,7 +46,7 @@ from repro.core.control_plane import (
 )
 from repro.core.markers import kernel
 from repro.core.types import PriorityCoefficients, ServiceClass
-from repro.telemetry.spans import child, moved_to_device, readback
+from repro.telemetry.spans import child, readback
 
 #: Back-compat name: the array-of-rows state is the ControlState.
 PoolArrays = ControlState
@@ -190,8 +193,6 @@ def arrays_from_pool(pool, now: float = 0.0
     pool cannot change any later admission decision.  The
     ``ControlState`` is the store's cached device mirror: after a tick
     this is O(1) Python (no per-row gather)."""
-    import numpy as np
-
     c = pool.store.col
     # scalar fallback rate for bucketless rows: effective-or-baseline,
     # the same `eff or baseline` rule the scalar §4.3 pipeline applies
@@ -223,23 +224,37 @@ def running_min_live(pool) -> float:
     return min(ws) if ws else float("inf")
 
 
-def _running_min_f32(pool, weights: jax.Array,
-                     row_of: dict[str, int]) -> float:
+@kernel(oracle="repro.core.vectorized.running_min_live")
+@jax.jit
+def owner_min(weights: jax.Array, owner: jax.Array) -> jax.Array:
+    """Minimum of the Eq. 1 row ``weights`` over the rows that
+    ``owner`` (bool, store width) marks; +inf when it marks none.
+
+    Both shapes are the store's capacity, so the program compiles once
+    per capacity whatever the owner count.  The min of a set of f32
+    values is exact and order-free, so this equals ``jnp.min`` over
+    the gathered owner rows bit for bit; the +inf fill never wins
+    against a live weight (Eq. 1 weights are finite)."""
+    from repro.core.control_plane import TRACE_COUNTS
+    TRACE_COUNTS["owner_min"] += 1             # repro: allow[retrace-hazard] -- trace-time counter: runs only while compiling, counts variants
+    return jnp.min(jnp.where(owner, weights, jnp.inf))
+
+
+def _running_min_f32(pool, weights: jax.Array) -> float:
     """float32 twin of :func:`running_min_live`, evaluated on the SAME
     Eq. 1 weight array handed to ``admit_quantum`` — one computation
     serves both the seed and the kernel, so a request whose own
     entitlement sets the threshold ties bit-exactly.
 
-    Owner rows come straight off the request table's owner column
-    (``np.unique`` — already the sorted distinct slot list) instead of
-    a per-record Python set walk; owner slots ARE store row indices,
-    which is what ``weights`` is indexed by."""
-    rows = pool.inflight_owner_slots()
-    if not rows.size:
-        return float("inf")
-    idx = jnp.asarray(rows, jnp.int32)
-    moved_to_device(idx.nbytes)
-    return float(readback(jnp.min(weights[idx])))
+    The owner rows (the set ``inflight_owner_slots`` lists: owner
+    slots ARE store row indices) are one scatter into a store-width
+    mask, uploaded where the store's rows live and reduced by
+    :func:`owner_min` — one fixed-shape program, however many owners
+    come and go."""
+    store, c = pool.store, pool.table.col
+    owner = np.zeros(bucket_width(store.capacity), bool)
+    owner[c["owner"][c["has_record"]]] = True
+    return float(readback(owner_min(weights, store.put_rows(owner))))
 
 
 @dataclasses.dataclass
@@ -271,8 +286,8 @@ def quantum_snapshot(pool, now: float) -> QuantumSnapshot:
     (the name→slot map and name list are C-speed container copies, so
     a held snapshot stays internally consistent even if membership
     churns after it was taken).  Inside a quantum it is the
-    ``gateway.snapshot`` span, which ends on the owner gather's
-    readback."""
+    ``gateway.snapshot`` span, which ends on the readback of the
+    owner-min seed (:func:`owner_min`)."""
     with child("gateway.snapshot", pool.spec.name):
         state, levels, infl, kvu = arrays_from_pool(pool, now)
         row_of = dict(pool.store.slot_of)
@@ -290,6 +305,6 @@ def quantum_snapshot(pool, now: float) -> QuantumSnapshot:
             pool_in_flight=pool.pool_in_flight(),
             pool_resident=pool.total_resident(),
             pool_conc_cap=float(pool.capacity().concurrency),
-            running_min_priority=_running_min_f32(pool, weights, row_of),
+            running_min_priority=_running_min_f32(pool, weights),
             pool_avg_slo=avg_slo,
         )

@@ -18,8 +18,8 @@ nest::
 
     gateway.quantum   handle_quantum (root)
       gateway.route     key -> legs: store reads, route_order_indexed
-      gateway.snapshot  quantum_snapshot: uploads, priority_batch and
-                        the owner gather up to its readback
+      gateway.snapshot  quantum_snapshot: uploads, priority_batch, the
+                        owner mask and owner_min up to its readback
       gateway.admit     padding, upload, admit_quantum, readback
       gateway.charge    ledger charges, admit_rows, demand, the 200s
       gateway.deny      Retry-After hints, register_deny_batch, the 429s

@@ -30,7 +30,7 @@ from repro.core.control_plane import (
 )
 from repro.core.fleet import FleetPlannerConfig, plan_fleet
 from repro.core.shard_plane import AXIS, shard_admit_quantum, shard_tick
-from repro.core.vectorized import admit_quantum
+from repro.core.vectorized import admit_quantum, owner_min
 
 #: ControlState field dtypes (class code, bound flag, then f32 columns)
 _STATE_DTYPES = {"class_code": jnp.int32, "bound": jnp.bool_}
@@ -163,4 +163,20 @@ def test_shard_admit_quantum_2p22_on_four_chips(mesh4):
     row, rep = NamedSharding(mesh4, P(AXIS)), NamedSharding(mesh4, P())
     compiled = compiled_for_tpu(shard_admit_quantum.lower(
         **admit_args(1 << 22, 10240, row, rep), mesh=mesh4))
+    assert "all-reduce" in compiled.as_text()
+
+
+def test_owner_min_2p17(one_chip):
+    n = 1 << 17
+    compiled_for_tpu(owner_min.lower(
+        spec((n,), jnp.float32, one_chip), spec((n,), jnp.bool_, one_chip)))
+
+
+def test_owner_min_2p22_on_four_chips(mesh4):
+    """The sharded store uploads the owner mask ``P("rows")`` beside
+    the weights: one reduction across the chips."""
+    n = 1 << 22
+    row = NamedSharding(mesh4, P(AXIS))
+    compiled = compiled_for_tpu(owner_min.lower(
+        spec((n,), jnp.float32, row), spec((n,), jnp.bool_, row)))
     assert "all-reduce" in compiled.as_text()
