@@ -219,7 +219,7 @@ class PoolManager:
         if rec is None:
             return None
         if rec.spill_from is not None:
-            self.transfer_spill_debt(rec, pool.spec.name, now)
+            self.transfer_spill_debts(pool.spec.name, [rec], now)
         return (pool.spec.name, rec)
 
     def transfer_spill_debt(self, rec: InFlight, serving_pool: str,
@@ -282,6 +282,23 @@ class PoolManager:
         src_st.debt = src_st.debt - delta
         return delta
 
+    def transfer_spill_debts(self, serving_pool: str, recs: list,
+                             now: float) -> None:
+        """:meth:`transfer_spill_debt` for each record of ``recs``
+        served by ``serving_pool``, in order, as the ``pool.spill_debt``
+        span; the debt moved, summed per preferred leg's pool, is
+        counted in the serving pool's telemetry."""
+        moved: dict[tuple[str, str], float] = {}
+        with child("pool.spill_debt", serving_pool):
+            for rec in recs:
+                delta = self.transfer_spill_debt(rec, serving_pool, now)
+                if delta > 0.0:
+                    pair = (rec.spill_from[0], serving_pool)
+                    moved[pair] = moved.get(pair, 0.0) + delta
+        tel = self.pools[serving_pool].telemetry
+        if tel is not None:
+            tel.record_spill_debt(moved)
+
     @hot_path
     def on_complete_batch(self, completions: list, now: float) -> list:
         """Batched :meth:`on_complete` — ``completions`` is a list of
@@ -316,8 +333,8 @@ class PoolManager:
             for k, i in enumerate(idxs):
                 if known[k]:
                     results[i] = (name, ents[k], float(settled[k]))
-            for rec in batch.spills:
-                self.transfer_spill_debt(rec, name, now)
+            if batch.spills:
+                self.transfer_spill_debts(name, batch.spills, now)
         return results
 
     def on_evict(self, request_id: str, now: float

@@ -276,6 +276,9 @@ class Gateway:
                 self.store.incr(f"admits:{leg.entitlement}", 1.0, now)
                 if hop > 0:
                     self.store.incr(f"spills:{api_key}", 1.0, now)
+                    if tel is not None:
+                        tel.record_spill_admits(leg.pool,
+                                                {legs[0][1].pool: 1})
                 if i_leg > 0:
                     # served by a spill leg: remember the PREFERRED leg
                     # so completion can transfer the debt credit
@@ -406,6 +409,7 @@ class Gateway:
                                     flightrec.VERDICT_UNKNOWN_KEY,
                                     flightrec.REASON_NONE)
 
+        k = 0
         while pending:
             # spills from different pools (and espec-miss skips) land in
             # group order — restore arrival order so every pool batch
@@ -421,9 +425,13 @@ class Gateway:
                 if p.leg_ptr < len(p.legs):
                     groups.setdefault(p.current()[1].pool, []).append(p)
             pending = []
-            for pool_name, batch in groups.items():
-                pending.extend(self._admit_batch(pool_name, batch,
-                                                 responses, now))
+            if not groups:
+                break
+            with child("gateway.round", index=k):
+                for pool_name, batch in groups.items():
+                    pending.extend(self._admit_batch(pool_name, batch,
+                                                     responses, now))
+            k += 1
         return responses
 
     def _finish_denied(self, p: _Pending, now: float) -> GatewayResponse:
@@ -866,6 +874,8 @@ class Gateway:
             pool, snap, rows, tokens, kvs, m)
 
         incr_acc: dict[str, float] = {}
+        #: admits off a later leg than the first, by the first leg's pool
+        spilled_from: dict[str, int] = {}
         with child("gateway.charge", pool_name):
             # -- scatter, pass 1: the quantum's charges, in replay order —
             # array-native: no per-request ``Charge`` objects, accepted
@@ -936,6 +946,8 @@ class Gateway:
                     if hop > 0:
                         key = p.req.api_key
                         n_spills[key] = n_spills.get(key, 0) + 1
+                        src = p.legs[0][1].pool
+                        spilled_from[src] = spilled_from.get(src, 0) + 1
                     if p.leg_ptr > 0:
                         first = p.legs[0][1]
                         spill_tags.append((k, (first.pool,
@@ -1000,6 +1012,7 @@ class Gateway:
             if incr_acc:
                 self.store.incr_many(incr_acc, now)
             if tel is not None:
+                tel.record_spill_admits(pool_name, spilled_from)
                 final_reasons = np.where(
                     charged, 0,
                     np.where(admitted, 3, reasons.astype(np.int64)))
@@ -1113,36 +1126,39 @@ class Gateway:
         Semantics per element match :meth:`on_complete` (the retained
         scalar oracle); StateStore counters are aggregated so the
         store is hit once per distinct entitlement per batch
-        (``last_latency`` keeps last-write-wins order)."""
+        (``last_latency`` keeps last-write-wins order).  The call is the
+        ``gateway.settle`` span; the debt transfers of requests served
+        on a spill leg are its ``pool.spill_debt`` children."""
         if not completions:
             return
-        settled = self.manager.on_complete_batch(
-            [(rid, out) for rid, out, _ in completions], now)
         tel = self.telemetry
-        tokens_incr: dict = {}
-        last_lat: dict = {}
-        done_pools: list[str] = []
-        done_ents: list[str] = []
-        done_lats: list[float] = []
-        for (_, out, lat), res in zip(completions, settled):
-            if res is None:
-                continue
-            ent = res[1]
-            tokens_incr[f"tokens:{ent}"] = \
-                tokens_incr.get(f"tokens:{ent}", 0.0) + float(out)
-            last_lat[ent] = lat
-            if tel is not None:
-                done_pools.append(res[0])
-                done_ents.append(ent)
-                done_lats.append(lat)
-        self.store.incr_many(tokens_incr, now)
-        for ent, lat in last_lat.items():
-            self.store.set(f"last_latency:{ent}", lat, now)
-        if tel is not None and done_ents:
-            # one SLO row-op for the whole drain (per-tier latency
-            # histograms + attainment counters)
-            tel.record_completions(now, done_pools, done_ents,
-                                   done_lats)
+        with span(tel, "gateway.settle", now=now):
+            settled = self.manager.on_complete_batch(
+                [(rid, out) for rid, out, _ in completions], now)
+            tokens_incr: dict = {}
+            last_lat: dict = {}
+            done_pools: list[str] = []
+            done_ents: list[str] = []
+            done_lats: list[float] = []
+            for (_, out, lat), res in zip(completions, settled):
+                if res is None:
+                    continue
+                ent = res[1]
+                tokens_incr[f"tokens:{ent}"] = \
+                    tokens_incr.get(f"tokens:{ent}", 0.0) + float(out)
+                last_lat[ent] = lat
+                if tel is not None:
+                    done_pools.append(res[0])
+                    done_ents.append(ent)
+                    done_lats.append(lat)
+            self.store.incr_many(tokens_incr, now)
+            for ent, lat in last_lat.items():
+                self.store.set(f"last_latency:{ent}", lat, now)
+            if tel is not None and done_ents:
+                # one SLO row-op for the whole drain (per-tier latency
+                # histograms + attainment counters)
+                tel.record_completions(now, done_pools, done_ents,
+                                       done_lats)
 
     def on_failure(self, request_id: str, now: float) -> None:
         self.manager.on_evict(request_id, now)

@@ -13,17 +13,23 @@ Quickstart::
     gw.telemetry.spans.rows()                # the spans, as arrays
 
 Spans (``repro.telemetry.spans``) are on whenever a ``Telemetry`` is
-attached, and their names are :data:`SPAN_NAMES`, nested as the calls
+attached, and their names are :data:`SPAN_NAMES` and
+:data:`LEG_SPAN_NAMES` (leg rounds and settles), nested as the calls
 nest::
 
     gateway.quantum   handle_quantum (root)
       gateway.route     key -> legs: store reads, route_order_indexed
+      gateway.round     one leg round of the generic path (its index);
+                        the snapshot .. record of each pool batch in it
       gateway.snapshot  quantum_snapshot: uploads, priority_batch, the
                         owner mask and owner_min up to its readback
       gateway.admit     padding, upload, admit_quantum, readback
       gateway.charge    ledger charges, admit_rows, demand, the 200s
       gateway.deny      Retry-After hints, register_deny_batch, the 429s
       gateway.record    flight rows, decision counters, store incr_many
+    gateway.settle    on_complete_batch (root)
+      pool.spill_debt   a pool's transfer_spill_debt calls, for the
+                        requests it served on a spill leg
     pool.tick         TokenPool.tick or one control_tick_pools group (root)
       pool.measure      window fold and the kernel-input uploads
       pool.kernel       dispatch through the alloc/weights readback
@@ -39,8 +45,11 @@ parent and the id of its root call, and enters a
 it beside the device's operations.  As a root closes its spans fold
 into the histogram ``repro_span_duration_seconds{span,pool}`` and the
 bytes they moved into ``repro_transfer_bytes_total{direction,span}``
-(``h2d`` / ``d2h``).  The Chrome timeline is drawn from the same table
-on the same clock; a simulator's ``now`` is kept in its args.
+(``h2d`` / ``d2h``).  Spill routing counts
+``repro_spill_admits_total{from_pool,to_pool}`` and
+``repro_spill_debt_moved_total{from_pool,to_pool}``.  The Chrome
+timeline is drawn from the same table on the same clock; a simulator's
+``now`` is kept in its args.
 """
 from repro.telemetry.export import (TraceBuffer, chrome_trace_json,
                                     json_snapshot, prometheus_text)
@@ -50,7 +59,7 @@ from repro.telemetry.flight import (DecisionTrace, FlightRecorder,
 from repro.telemetry.registry import (Counter, Gauge, Histogram,
                                       MetricsRegistry)
 from repro.telemetry.slo import SloTracker, TIER_NAMES
-from repro.telemetry.spans import SPAN_NAMES, SpanTable
+from repro.telemetry.spans import LEG_SPAN_NAMES, SPAN_NAMES, SpanTable
 
 __all__ = [
     "Counter",
@@ -59,6 +68,7 @@ __all__ = [
     "FlightRow",
     "Gauge",
     "Histogram",
+    "LEG_SPAN_NAMES",
     "MetricsRegistry",
     "SPAN_NAMES",
     "SloTracker",

@@ -189,6 +189,8 @@ class TraceBuffer:
                     args[f"{col}_bytes"] = int(rows[col][i])
             if rows["cache_hit"][i] >= 0:
                 args["cache_hit"] = bool(rows["cache_hit"][i])
+            if rows["index"][i] >= 0:
+                args["index"] = int(rows["index"][i])
             start, end = float(rows["start"][i]), float(rows["end"][i])
             slices.append({"name": rows["name"][i], "ph": "X", "pid": 1,
                            "tid": tid, "ts": self._us(start),

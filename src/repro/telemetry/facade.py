@@ -12,11 +12,11 @@ Recording discipline matches the rest of the control plane:
 * per-EVENT surfaces (``on_tick``, ``on_quantum``, ``on_plan``,
   incidents) fire once per tick/quantum/plan — O(pools) per tick, not
   O(requests) — so they may use the scalar recorders;
-* spans (``repro.telemetry.spans``) time the quantum, tick and plan and
-  their parts; as each root span closes, its spans fold into
-  ``repro_span_duration_seconds{span,pool}`` and the bytes they moved
-  into ``repro_transfer_bytes_total{direction,span}`` — one batch
-  row-op each per root, O(spans);
+* spans (``repro.telemetry.spans``) time the quantum, tick, plan and
+  settle and their parts; as each root span closes, its spans
+  fold into ``repro_span_duration_seconds{span,pool}`` and the bytes
+  they moved into ``repro_transfer_bytes_total{direction,span}`` — one
+  batch row-op each per root, O(spans);
 * the scalar ``record_decision`` twin serves the sequential
   ``Gateway.handle`` path and doubles as the flight-recorder parity
   oracle.
@@ -41,7 +41,8 @@ from repro.telemetry.export import (TraceBuffer, chrome_trace_json,
 from repro.telemetry.flight import FlightRecorder
 from repro.telemetry.registry import MetricsRegistry
 from repro.telemetry.slo import TIER_NAMES, SloTracker
-from repro.telemetry.spans import SPAN_CAPACITY, SPAN_NAMES, SpanTable
+from repro.telemetry.spans import _NAMES as _SPAN_NAMES
+from repro.telemetry.spans import SPAN_CAPACITY, SpanTable
 
 __all__ = ["Telemetry"]
 
@@ -77,6 +78,17 @@ class Telemetry:
             help="Bytes moved between host and device, by the span "
                  "that moved them.",
             labels=("direction", "span"))
+        self.spill_admits = r.counter(
+            "repro_spill_admits_total",
+            help="Requests admitted on a later leg of their route than "
+                 "the first, by the first leg's pool and the admitting "
+                 "pool.",
+            labels=("from_pool", "to_pool"))
+        self.spill_debt_moved = r.counter(
+            "repro_spill_debt_moved_total",
+            help="Debt moved at completion of spill-served requests, "
+                 "from the preferred leg's pool to the serving pool.",
+            labels=("from_pool", "to_pool"))
         self.quantum_requests = r.counter(
             "repro_gateway_quantum_requests_total",
             help="Requests processed through handle_quantum.")
@@ -241,6 +253,28 @@ class Telemetry:
         self.slo.observe_rows(np.asarray(latencies, np.float64),
                               codes, slos)
 
+    @hot_path
+    def record_spill_admits(self, to_pool: str,
+                            from_pools: dict[str, int]) -> None:
+        """One pool dispatch's admits off a later leg than the first:
+        request counts by the pool of each request's first leg."""
+        if from_pools:
+            self.spill_admits.inc_rows(
+                np.asarray([self.spill_admits.series((src, to_pool))
+                            for src in from_pools], np.int64),
+                np.asarray(list(from_pools.values()), np.float64))
+
+    @hot_path
+    def record_spill_debt(self, moved: dict[tuple[str, str], float]
+                          ) -> None:
+        """One settle's spill-debt transfers, summed per (from pool,
+        to pool)."""
+        if moved:
+            self.spill_debt_moved.inc_rows(
+                np.asarray([self.spill_debt_moved.series(pair)
+                            for pair in moved], np.int64),
+                np.asarray(list(moved.values()), np.float64))
+
     def record_decision(self, pool_name: str, now: float,
                         request_id: str, leg: int,
                         entitlement: Optional[str], admitted: bool,
@@ -290,7 +324,7 @@ class Telemetry:
         row-op each; series ids are dict lookups, O(spans))."""
         t = self.spans
         r = np.arange(root, t.next_id) & (t.capacity - 1)
-        names = [SPAN_NAMES[c] for c in t.name[r].tolist()]
+        names = [_SPAN_NAMES[c] for c in t.name[r].tolist()]
         pools = [t.pools[p] if p >= 0 else "" for p in t.pool[r].tolist()]
         self.span_duration.observe_rows(
             t.end[r] - t.start[r],

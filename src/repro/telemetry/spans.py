@@ -1,12 +1,14 @@
 """Program spans: where the control plane spends its time, on one clock.
 
 A span is one timed stretch of a control-plane call.  It records its
-name (one of :data:`SPAN_NAMES`), its start and end on
-``time.perf_counter``, the span open around it (its parent) and the id
-of its root call: the quantum (``gateway.quantum``), the tick
-(``pool.tick``) or the plan (``fleet.plan``) it belongs to.  Every
-span of a quantum, and so every request the quantum decides, shares
-that root id.
+name (one of :data:`SPAN_NAMES` or :data:`LEG_SPAN_NAMES`), its start
+and end on ``time.perf_counter``, the span open around it (its parent)
+and the id of its root call: the quantum (``gateway.quantum``), the tick
+(``pool.tick``), the plan (``fleet.plan``) or the settle
+(``gateway.settle``) it belongs to, and an index where a span repeats
+in its parent (the leg round of ``gateway.round``).  Every span of a
+quantum, and so every request the quantum decides, shares that root
+id.
 
 Spans live in a :class:`SpanTable`, a bounded ring of preallocated
 arrays that a ``Telemetry`` owns; they are read out at the end
@@ -44,10 +46,10 @@ from typing import Callable, Optional
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-__all__ = ["SPAN_CAPACITY", "SPAN_NAMES", "SpanTable", "child",
-           "moved_to_device", "readback", "span"]
+__all__ = ["LEG_SPAN_NAMES", "SPAN_CAPACITY", "SPAN_NAMES", "SpanTable",
+           "child", "moved_to_device", "readback", "span"]
 
-#: every span the program records, roots first in each family
+#: the spans every fleet's calls record, roots first in each family
 SPAN_NAMES = (
     "gateway.quantum", "gateway.route", "gateway.snapshot",
     "gateway.admit", "gateway.charge", "gateway.deny", "gateway.record",
@@ -55,7 +57,15 @@ SPAN_NAMES = (
     "fleet.plan", "fleet.kernel", "fleet.rebalance",
     "compile",
 )
-_CODE = {name: i for i, name in enumerate(SPAN_NAMES)}
+#: the spans of leg routing and settlement: each leg round of the
+#: generic quantum (``gateway.round``, under ``gateway.quantum``;
+#: single-leg routes take the fast path, which has no rounds), the
+#: settle of a batch of completions (``gateway.settle``, a root) and the
+#: debt transfers of its requests served on a spill leg
+#: (``pool.spill_debt``)
+LEG_SPAN_NAMES = ("gateway.round", "gateway.settle", "pool.spill_debt")
+_NAMES = SPAN_NAMES + LEG_SPAN_NAMES
+_CODE = {name: i for i, name in enumerate(_NAMES)}
 #: spans a ``Telemetry`` keeps: a gateway at 4000 requests/s records
 #: ~1000 a minute (compiles included), so the newest half hour or so
 SPAN_CAPACITY = 1 << 15
@@ -114,6 +124,7 @@ class SpanTable:
         self.h2d = np.zeros(capacity, np.int64)         # bytes
         self.d2h = np.zeros(capacity, np.int64)
         self.cache_hit = np.zeros(capacity, np.int8)    # -1: not a compile
+        self.index = np.zeros(capacity, np.int32)       # -1: none
         self.next_id = 0
         #: the clock's origin for exports (``perf_counter`` at creation)
         self.t0 = time.perf_counter()
@@ -131,7 +142,7 @@ class SpanTable:
         return pid
 
     def _row(self, code: int, pool: int, parent: int, root: int,
-             now: float) -> int:
+             now: float, index: int = -1) -> int:
         sid = self.next_id
         self.next_id = sid + 1
         r = sid & self._mask
@@ -143,11 +154,12 @@ class SpanTable:
         self.h2d[r] = 0
         self.d2h[r] = 0
         self.cache_hit[r] = -1
+        self.index[r] = index
         self.end[r] = np.nan
         return sid
 
     def open(self, name: str, pool: Optional[str] = None,
-             now: Optional[float] = None) -> _Span:
+             now: Optional[float] = None, index: int = -1) -> _Span:
         """Open ``name`` under the innermost open span if it is this
         table's (inheriting its pool unless ``pool`` is given), else as
         a root.  Use as a context manager."""
@@ -160,7 +172,7 @@ class SpanTable:
             parent, root = -1, -1
             pid = -1 if pool is None else self._pool_id(pool)
         sid = self._row(_CODE[name], pid, parent, root,
-                        np.nan if now is None else now)
+                        np.nan if now is None else now, index)
         ann = TraceAnnotation(name)
         ann.__enter__()
         s = _Span(self, sid, ann)
@@ -191,8 +203,9 @@ class SpanTable:
         ``name`` and ``pool`` (strings; '' for no pool), ``parent`` (-1
         for a root), ``root``, ``start`` and ``end`` (``perf_counter``
         seconds), ``now`` (the caller's clock, NaN where none was
-        given), ``h2d`` and ``d2h`` (bytes) and ``cache_hit`` (compiles:
-        1 from the persistent cache, 0 compiled; -1 for other spans)."""
+        given), ``h2d`` and ``d2h`` (bytes), ``cache_hit`` (compiles:
+        1 from the persistent cache, 0 compiled; -1 for other spans) and
+        ``index`` (the leg round of ``gateway.round``; -1 for none)."""
         ids = np.arange(max(self.next_id - self.capacity, 0), self.next_id)
         r = ids & self._mask
         done = ~np.isnan(self.end[r])
@@ -200,7 +213,7 @@ class SpanTable:
         pools = np.asarray(self.pools + [""], object)
         return {
             "id": ids,
-            "name": np.asarray(SPAN_NAMES, object)[self.name[r]],
+            "name": np.asarray(_NAMES, object)[self.name[r]],
             "pool": pools[self.pool[r]],
             "parent": self.parent[r],
             "root": self.root[r],
@@ -210,6 +223,7 @@ class SpanTable:
             "h2d": self.h2d[r],
             "d2h": self.d2h[r],
             "cache_hit": self.cache_hit[r],
+            "index": self.index[r],
         }
 
 
@@ -222,13 +236,13 @@ def span(tel, name: str, pool: Optional[str] = None,
     return tel.spans.open(name, pool, now)
 
 
-def child(name: str, pool: Optional[str] = None):
+def child(name: str, pool: Optional[str] = None, index: int = -1):
     """Open ``name`` under the innermost open span, in its table;
     nothing records when no span is open."""
     if not _OPEN:
         return _NO_SPAN
     top = _OPEN[-1]
-    return top.table.open(name, pool)
+    return top.table.open(name, pool, index=index)
 
 
 def moved_to_device(nbytes: int) -> None:

@@ -164,9 +164,12 @@ class TestProgramSpans:
         q = int(r["id"][only(r, "gateway.quantum")])
         # round 0 through the kernel, then the 429s of exhausted routes
         assert children(r, q) == [
-            "gateway.route", "gateway.snapshot", "gateway.admit",
-            "gateway.charge", "gateway.deny", "gateway.record",
-            "gateway.deny"]
+            "gateway.route", "gateway.round", "gateway.deny"]
+        rnd = only(r, "gateway.round")
+        assert r["index"][rnd] == 0
+        assert children(r, int(r["id"][rnd])) == [
+            "gateway.snapshot", "gateway.admit", "gateway.charge",
+            "gateway.deny", "gateway.record"]
         tick = only(r, "pool.tick")
         assert r["pool"][tick] == ""
         assert children(r, int(r["id"][tick])) == [
