@@ -59,7 +59,7 @@ import numpy as np
 
 from repro.core import control_plane
 from repro.core.autoscaler import ScaleDecision, replicas_for
-from repro.core.markers import kernel
+from repro.core.markers import hot_path, kernel
 from repro.core.pool import TickRecord, TokenPool
 from repro.core.types import Resources, ServiceClass
 from repro.telemetry.spans import child, moved_to_device, readback
@@ -69,6 +69,9 @@ from repro.telemetry.spans import child, moved_to_device, readback
 REASONS = ("steady", "scale_up:reserved", "scale_up:demand",
            "hold:cooldown", "scale_down")
 _STEADY, _UP_RESERVED, _UP_DEMAND, _HOLD, _DOWN = range(5)
+
+_ELASTIC = control_plane.CLASS_CODES[ServiceClass.ELASTIC]
+_SPOT = control_plane.CLASS_CODES[ServiceClass.SPOT]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +132,11 @@ class FleetPlan:
     #: per-round decisions which repeat desired > current every tick
     #: while provisioning lag is converging
     scale_events: dict[str, tuple[int, int]] = dataclasses.field(
+        default_factory=dict)
+    #: elastic/spot rows starved this round, by (pool, reason) with
+    #: reason "debt" or "starved_demand" — the rows the rebalance pass
+    #: handles one by one
+    starved_rows: dict[tuple[str, str], int] = dataclasses.field(
         default_factory=dict)
 
 
@@ -246,7 +254,71 @@ class FleetPlanner:
         per-name dict walk."""
         if record is None:
             return pool.demand_total_tps()
-        return float(sum(record.demand_tps.values()))
+        demand = record.column("demand_tps")
+        values = (record.demand_tps.values() if demand is None
+                  else demand.tolist())
+        # Python's float sum (compensated) in row order: the bits of
+        # summing the record's dict, which ``np.sum`` would not give
+        return float(sum(values))
+
+    @staticmethod
+    def _record_rows(pool: TokenPool, record: Optional[TickRecord],
+                     slots: np.ndarray
+                     ) -> Optional[tuple[np.ndarray, np.ndarray]]:
+        """The record's (demand, allocation) in float64 over the pool's
+        live ``slots``, 0 for a row the record lacks; None without a
+        record.  A record from a tick over today's membership carries
+        the store's cached live-name list, so its arrays line up as
+        they are; otherwise (rows added or removed since the tick, a
+        record built from dicts) they are aligned by name."""
+        if record is None:
+            return None
+        store = pool.store
+        demand = record.column("demand_tps")
+        alloc = record.column("allocations")
+        if demand is not None and record.names is store.live_names():
+            return (np.asarray(demand, np.float64),
+                    np.asarray(alloc, np.float64))
+        if demand is None:
+            names = store.live_names()
+            want, got = record.demand_tps, record.allocations
+            return (np.fromiter((want.get(n, 0.0) for n in names),
+                                np.float64, len(names)),
+                    np.fromiter((got.get(n, 0.0) for n in names),
+                                np.float64, len(names)))
+        rows = np.fromiter((store.slot_of.get(n, -1) for n in record.names),
+                           np.int64, len(record.names))
+        kept = rows >= 0
+        out = []
+        for col in (demand, alloc):
+            full = np.zeros(store.capacity, np.float64)
+            full[rows[kept]] = np.asarray(col, np.float64)[kept]
+            out.append(full[slots])
+        return out[0], out[1]
+
+    def _starved_rows(self, pool: TokenPool,
+                      record: Optional[TickRecord]
+                      ) -> tuple[np.ndarray, np.ndarray]:
+        """The pool's elastic/spot rows that are starved now, as
+        (store slots, reason is "debt") — the reason "starved_demand"
+        otherwise.  Debt is compared in float64, as ``float(debt)``
+        against the threshold."""
+        cfg = self.config
+        c = pool.store.col
+        slots = pool.store.live_slots()
+        code = c["class_code"][slots]
+        elastic = code == _ELASTIC
+        debt = elastic & (c["debt"][slots].astype(np.float64)
+                          >= cfg.debt_migrate_threshold)
+        starved = debt
+        rows = self._record_rows(pool, record, slots)
+        if rows is not None:
+            demand, alloc = rows
+            starved = debt | ((elastic | (code == _SPOT))
+                              & (demand > 1e-9)
+                              & (alloc < cfg.starve_frac * demand))
+        hit = np.flatnonzero(starved)
+        return slots[hit], debt[hit]
 
     def _arrays(self, pools: dict[str, TokenPool],
                 records: dict[str, TickRecord]) -> tuple[list, dict]:
@@ -288,7 +360,8 @@ class FleetPlanner:
              now: float = 0.0) -> FleetPlan:
         """One planning round over the fleet: ONE ``plan_fleet``
         dispatch (the ``fleet.kernel`` span, upload through readback)
-        + the Python-side rebalance pass (``fleet.rebalance``)."""
+        + the rebalance pass over columns and tick arrays
+        (``fleet.rebalance``)."""
         records = records or {}
         self._plans += 1
         # drop state of pools that left the fleet
@@ -320,11 +393,113 @@ class FleetPlanner:
             if over > 1e-6:
                 unmet[name] = over
         with child("fleet.rebalance"):
-            migrations = self._rebalance(pools, records, names, need, arr)
+            migrations, starved = self._rebalance(pools, records, names,
+                                                  need, arr)
         return FleetPlan(decisions=decisions, migrations=migrations,
-                         unmet_replicas=unmet)
+                         unmet_replicas=unmet, starved_rows=starved)
 
     # -- rebalancing -----------------------------------------------------------
+    @hot_path
+    def _rebalance(self, pools: dict[str, TokenPool],
+                   records: dict[str, TickRecord], names: list[str],
+                   need: np.ndarray, arr: dict
+                   ) -> tuple[list[RebalanceProposal],
+                              dict[tuple[str, str], int]]:
+        """Rebalance proposals, and the count of starved rows by (pool,
+        reason).  The starvation flags are masks over each pool's
+        columns and its tick record; Python touches only the rows that
+        are starved now.  Entitlement names are unique in the fleet."""
+        cfg = self.config
+        hi = {n: float(arr["hi"][i]) for i, n in enumerate(names)}
+        need_by = {n: float(need[i]) for i, n in enumerate(names)}
+        slack = {n: hi[n] - need_by[n] for n in names}
+
+        # 1. persistence counters: one more plan for each starved row,
+        #    and every other entitlement's counter dropped
+        prev, self._starved = self._starved, {}
+        starved: dict[str, tuple[list[str], np.ndarray, np.ndarray]] = {}
+        counts: dict[tuple[str, str], int] = {}
+        for pname in names:
+            pool = pools[pname]
+            slots, is_debt = self._starved_rows(pool, records.get(pname))
+            name_of = pool.store.name_of
+            ents = [name_of[s] for s in slots.tolist()]
+            for ent in ents:
+                self._starved[ent] = prev.get(ent, 0) + 1
+            starved[pname] = (ents, slots, is_debt)
+            n_debt = int(np.count_nonzero(is_debt))
+            counts[(pname, "debt")] = n_debt
+            counts[(pname, "starved_demand")] = len(ents) - n_debt
+
+        # 2. proposals: scarce pools shed their most-indebted starved
+        #    entitlements onto the slackest pool that can hold them
+        proposals: list[RebalanceProposal] = []
+        for src in names:
+            if need_by[src] <= hi[src] + 1e-6:
+                continue                             # not scarce
+            pool = pools[src]
+            ents, slots, is_debt = starved[src]
+            candidates = []
+            for ent, debt, by_debt in zip(
+                    ents, pool.store.col["debt"][slots].tolist(),
+                    is_debt.tolist()):
+                if self._starved.get(ent, 0) < cfg.starve_persistence_ticks:
+                    continue
+                if self._plans - self._cooldown.get(ent, -10**9) \
+                        < cfg.migrate_cooldown_ticks:
+                    continue
+                candidates.append(
+                    (debt, ent, "debt" if by_debt else "starved_demand"))
+            candidates.sort(key=lambda c: (-c[0], c[1]))
+            moved = 0
+            for debt, ent, why in candidates:
+                if moved >= cfg.max_migrations_per_pool:
+                    break
+                espec = pool.entitlements[ent]
+                dst = self._pick_target(pools, names, src, espec, slack)
+                if dst is None:
+                    continue
+                slack[dst] -= _reserve_replicas(espec,
+                                                pools[dst])
+                self._cooldown[ent] = self._plans
+                self._starved.pop(ent, None)
+                proposals.append(RebalanceProposal(
+                    entitlement=ent, src=src, dst=dst, debt=float(debt),
+                    baseline_tps=espec.baseline.tokens_per_second,
+                    reason=why))
+                moved += 1
+        return proposals, counts
+
+    def _pick_target(self, pools: dict[str, TokenPool], names: list[str],
+                     src: str, espec, slack: dict[str, float]
+                     ) -> Optional[str]:
+        """Slackest pool (≠ src) whose remaining headroom under
+        maxReplicas can absorb the entitlement's baseline reserve."""
+        best, best_slack = None, 0.0
+        for dst in names:
+            if dst == src:
+                continue
+            remaining = slack[dst] - _reserve_replicas(espec, pools[dst])
+            if remaining < -1e-6:
+                continue
+            if best is None or slack[dst] > best_slack:
+                best, best_slack = dst, slack[dst]
+        return best
+
+
+class ReferenceFleetPlanner(FleetPlanner):
+    """The rebalance pass as a per-entitlement loop over names, status
+    views and the record's dicts: the parity oracle of
+    :class:`FleetPlanner`'s array pass, called only by tests (as
+    ``control_plane.reference_tick`` is).  Same kernel, same state."""
+
+    @staticmethod
+    def pool_demand(pool: TokenPool,
+                    record: Optional[TickRecord]) -> float:
+        if record is None:
+            return pool.demand_total_tps()
+        return float(sum(record.demand_tps.values()))
+
     def _starvation(self, pool: TokenPool, name: str,
                     record: Optional[TickRecord]) -> Optional[str]:
         """Starvation signal for one elastic/spot entitlement, or None."""
@@ -343,7 +518,9 @@ class FleetPlanner:
 
     def _rebalance(self, pools: dict[str, TokenPool],
                    records: dict[str, TickRecord], names: list[str],
-                   need: np.ndarray, arr: dict) -> list[RebalanceProposal]:
+                   need: np.ndarray, arr: dict
+                   ) -> tuple[list[RebalanceProposal],
+                              dict[tuple[str, str], int]]:
         cfg = self.config
         hi = {n: float(arr["hi"][i]) for i, n in enumerate(names)}
         need_by = {n: float(need[i]) for i, n in enumerate(names)}
@@ -351,15 +528,19 @@ class FleetPlanner:
 
         # 1. persistence counters for every migratable entitlement
         live: set[str] = set()
+        counts: dict[tuple[str, str], int] = {}
         for pname in names:
             pool = pools[pname]
             rec = records.get(pname)
+            counts[(pname, "debt")] = counts[(pname, "starved_demand")] = 0
             for ent, espec in pool.entitlements.items():
                 if espec.qos.service_class not in (ServiceClass.ELASTIC,
                                                    ServiceClass.SPOT):
                     continue
                 live.add(ent)
-                if self._starvation(pool, ent, rec) is not None:
+                why = self._starvation(pool, ent, rec)
+                if why is not None:
+                    counts[(pname, why)] += 1
                     self._starved[ent] = self._starved.get(ent, 0) + 1
                 else:
                     self._starved.pop(ent, None)
@@ -406,20 +587,4 @@ class FleetPlanner:
                     baseline_tps=espec.baseline.tokens_per_second,
                     reason=why))
                 moved += 1
-        return proposals
-
-    def _pick_target(self, pools: dict[str, TokenPool], names: list[str],
-                     src: str, espec, slack: dict[str, float]
-                     ) -> Optional[str]:
-        """Slackest pool (≠ src) whose remaining headroom under
-        maxReplicas can absorb the entitlement's baseline reserve."""
-        best, best_slack = None, 0.0
-        for dst in names:
-            if dst == src:
-                continue
-            remaining = slack[dst] - _reserve_replicas(espec, pools[dst])
-            if remaining < -1e-6:
-                continue
-            if best is None or slack[dst] > best_slack:
-                best, best_slack = dst, slack[dst]
-        return best
+        return proposals, counts

@@ -194,6 +194,17 @@ class TickRecord:
         rec._cache = {}
         return rec
 
+    @property
+    def names(self) -> Optional[list[str]]:
+        """Row names of an array-backed record (the list object the
+        tick passed in), or None for one built from dicts."""
+        return self._names
+
+    def column(self, key: str) -> Optional[np.ndarray]:
+        """The compact array behind ``key`` (row i ↔ ``names[i]``)
+        without materializing its dict, or None for a dict record."""
+        return None if self._arrays is None else self._arrays[key]
+
     def _dict(self, key: str) -> dict:
         d = self._cache.get(key)
         if d is None:

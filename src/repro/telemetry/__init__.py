@@ -36,7 +36,9 @@ nest::
       pool.absorb       the kernel's state adopted, buckets re-rated
     fleet.plan        Gateway.plan_quantum (root)
       fleet.kernel      plan_fleet from upload through readback
-      fleet.rebalance   _rebalance with _starvation
+      fleet.rebalance   _rebalance: starvation masks over the store
+                        columns and the tick record, then Python over
+                        the starved rows alone
     compile           a backend compile, under the span that caused it
 
 Every span is timed on one clock (``time.perf_counter``), carries its
@@ -47,7 +49,8 @@ into the histogram ``repro_span_duration_seconds{span,pool}`` and the
 bytes they moved into ``repro_transfer_bytes_total{direction,span}``
 (``h2d`` / ``d2h``).  Spill routing counts
 ``repro_spill_admits_total{from_pool,to_pool}`` and
-``repro_spill_debt_moved_total{from_pool,to_pool}``.  The Chrome
+``repro_spill_debt_moved_total{from_pool,to_pool}``; each plan sets
+``repro_plan_starved_rows{pool,reason}``.  The Chrome
 timeline is drawn from the same table on the same clock; a simulator's
 ``now`` is kept in its args.
 """

@@ -104,6 +104,12 @@ class Telemetry:
             "repro_pool_replicas_desired",
             help="Fleet planner's desired replica count.",
             labels=("pool",))
+        self.plan_starved = r.gauge(
+            "repro_plan_starved_rows",
+            help="Elastic/spot rows starved at the last plan, by reason "
+                 "(debt, starved_demand): the rows the rebalance pass "
+                 "handles one by one.",
+            labels=("pool", "reason"))
         self.scale_events = r.counter(
             "repro_fleet_scale_events_total",
             help="Authorized scale transitions by direction.",
@@ -368,6 +374,9 @@ class Telemetry:
         for name, d in plan.decisions.items():
             self.replicas.set(self.replicas.series((name,)),
                               float(d.desired))
+        for labels, n in plan.starved_rows.items():
+            self.plan_starved.set(self.plan_starved.series(labels),
+                                  float(n))
         for name, (old, new) in plan.scale_events.items():
             if new == old:
                 continue
