@@ -6,9 +6,9 @@
 
 One chip.  A three-pool fleet is built through the public ``repro.core``
 / ``repro.gateway`` surfaces: ``main`` holds 2^17 entitlements
-(guaranteed, elastic and spot, one key each, single-leg routes — the
-``_quantum_fast`` path); ``east`` and ``west`` hold a few thousand keys
-whose routes spill ``east`` → ``west`` (the generic leg-round path).
+(guaranteed, elastic and spot, one key each, single-leg routes: one
+round a quantum); ``east`` and ``west`` hold a few thousand keys
+whose routes spill ``east`` → ``west`` (leg rounds).
 Quanta of 8192 requests, Zipf-skewed over the keys with long-tailed
 input/output lengths drawn from ``--seed``, go through
 ``Gateway.handle_quantum``; admitted requests are dispatched and settled

@@ -1,10 +1,10 @@
-"""Differential replay: one scenario, three admission pipelines.
+"""Differential replay: one scenario, two admission pipelines.
 
 The same seeded :class:`~repro.chaos.scenario.Scenario` is executed
 under (a) scalar per-request admission (``Gateway.handle`` — the
-parity oracle), (b) the generic quantum path
-(``Gateway.handle_quantum`` with the fast path disabled), and (c) the
-fused fast path.  The three runs must be **decision-identical**: every
+parity oracle) and (b) the batched quantum path
+(``Gateway.handle_quantum``).  The two runs must be
+**decision-identical**: every
 request gets the same terminal state, deny reason, admitting pool and
 spill-hop count, and the flight recorder (PR 8's admission black box)
 must hold structurally identical per-request decision traces — same
@@ -25,12 +25,9 @@ from typing import Optional
 
 from repro.chaos.scenario import Scenario, build_sim
 
-#: (label, admission_mode, quantum_fast)
-REPLAY_MODES = (
-    ("scalar", "scalar", False),
-    ("quantum", "quantum", False),
-    ("quantum_fast", "quantum", True),
-)
+#: the simulator's ``admission_mode`` per replay, the scalar baseline
+#: first
+REPLAY_MODES = ("scalar", "quantum")
 
 #: f32-vs-f64 slack for priorities recorded along the two pipelines
 PRIORITY_RTOL = 1e-4
@@ -154,16 +151,14 @@ def run_replay(scenario: Scenario, duration_s: Optional[float] = None,
     """Execute ``scenario`` once per mode and diff every mode against
     the scalar baseline (the first entry of ``modes``)."""
     traces: dict = {}
-    for label, admission_mode, fast in modes:
-        sim = build_sim(scenario, admission_mode=admission_mode,
-                        quantum_fast=fast, telemetry=True)
+    for mode in modes:
+        sim = build_sim(scenario, admission_mode=mode, telemetry=True)
         sim.run(duration_s or scenario.duration_s)
-        traces[label] = capture_trace(sim, label)
-    labels = [m[0] for m in modes]
-    base = traces[labels[0]]
+        traces[mode] = capture_trace(sim, mode)
+    base = traces[modes[0]]
     mismatches: list = []
-    for label in labels[1:]:
-        for line in diff_traces(base, traces[label]):
-            mismatches.append(f"[{labels[0]} vs {label}] {line}")
+    for mode in modes[1:]:
+        for line in diff_traces(base, traces[mode]):
+            mismatches.append(f"[{modes[0]} vs {mode}] {line}")
     return ReplayResult(scenario=scenario.name, traces=traces,
                         mismatches=mismatches)

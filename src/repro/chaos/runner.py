@@ -46,12 +46,11 @@ def install_checkers(sim, checkers: list, violations: list,
 
 
 def run_scenario(scenario: Scenario, admission_mode: str = "quantum",
-                 quantum_fast: bool = True,
                  checkers: Optional[list] = None,
                  check_interval_steps: int = 1) -> dict:
     """Execute one scenario under the full invariant registry."""
     sim = build_sim(scenario, admission_mode=admission_mode,
-                    quantum_fast=quantum_fast, telemetry=True)
+                    telemetry=True)
     for pool in sim.manager.pools.values():
         pool.ledger.enable_level_audit()
     if checkers is None:
@@ -77,7 +76,6 @@ def run_scenario(scenario: Scenario, admission_mode: str = "quantum",
         "seed": scenario.seed,
         "duration_s": scenario.duration_s,
         "admission_mode": admission_mode,
-        "quantum_fast": quantum_fast,
         "p99_bound_s": scenario.p99_bound_s,
         "checkers": [{"name": c.name, "scope": c.scope,
                       "description": c.description} for c in checkers],

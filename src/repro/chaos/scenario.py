@@ -145,16 +145,16 @@ def schedule_event(sim: MultiPoolSimulator, ev: ScenarioEvent) -> None:
 
 
 def build_sim(scenario: Scenario, admission_mode: str = "quantum",
-              quantum_fast: bool = True,
               telemetry=True) -> MultiPoolSimulator:
     """Materialize one simulator for ``scenario``.
 
     Fresh ``Workload`` / ``PoolSite`` objects are built per call
     (``set_rate`` events mutate workloads in place), the deterministic
     retry backoff is installed, and every scripted event is scheduled.
-    ``admission_mode`` / ``quantum_fast`` select the admission pipeline
-    under test — the replay engine calls this three times with the
-    same scenario and diffs the resulting decision traces.
+    ``admission_mode`` selects the admission pipeline under test
+    (``"scalar"``, the oracle, or ``"quantum"``) — the replay engine
+    calls this once per mode with the same scenario and diffs the
+    resulting decision traces.
     """
     workloads = [Workload(**dict(kw)) for kw in scenario.workloads]
     sites = [PoolSite(**dict(kw)) for kw in scenario.sites]
@@ -168,7 +168,6 @@ def build_sim(scenario: Scenario, admission_mode: str = "quantum",
         provision_lag_s=scenario.provision_lag_s,
         drain_s=scenario.drain_s,
         telemetry=telemetry)
-    sim.gateway.quantum_fast_enabled = quantum_fast
     sim.retry_backoff = seeded_backoff(scenario)
     for ev in scenario.events:
         schedule_event(sim, ev)

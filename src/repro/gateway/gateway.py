@@ -18,13 +18,15 @@ Two request paths share these semantics:
 - :meth:`Gateway.handle` — one request through the scalar §4.3
   pipeline (``AdmissionController.decide``); the per-request fallback
   and the parity oracle for the batched path;
-- :meth:`Gateway.handle_quantum` — the DEFAULT hot path at scale: all
-  requests of one scheduling quantum are grouped per (pool, leg), each
-  pool is snapshotted once, and ONE fused ``admit_quantum`` dispatch
-  replays the §4.3 pipeline for the whole group; denials spill into
-  the next leg's batch, so routes keep their ``route_order``
-  semantics.  Requests are padded to a power-of-two per dispatch so
-  quantum-size churn does not retrace the kernel.
+- :meth:`Gateway.handle_quantum` — the DEFAULT hot path at scale, one
+  path for every route shape: the requests of one scheduling quantum
+  are grouped per (key, token shape) and routed once; leg round ``k``
+  sends every undecided request to its ``k``-th live leg, each pool is
+  snapshotted once, and ONE fused ``admit_quantum`` dispatch replays
+  the §4.3 pipeline for the pool's batch; denials spill into the next
+  round, so routes keep their ``route_order`` semantics.  Requests are
+  padded to a power-of-two per dispatch so quantum-size churn does not
+  retrace the kernel.
 
 State lives in the StateStore (Redis contract): key → route mapping and
 per-entitlement counters, so a real deployment can point this class at
@@ -32,6 +34,7 @@ an actual Redis.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from itertools import chain
@@ -62,7 +65,7 @@ from repro.core.vectorized import admit_quantum, quantum_snapshot
 from repro.telemetry import flight as flightrec
 from repro.telemetry.spans import child, moved_to_device, readback, span
 
-#: C-speed attribute extractors for the quantum fast path.
+#: C-speed attribute extractors for the quantum path.
 _Q_RID = attrgetter("request_id")
 _Q_KV = attrgetter("kv_bytes_per_token")
 
@@ -106,31 +109,6 @@ class QuantumRequest:
     kv_bytes_per_token: float = 0.0
 
 
-@dataclasses.dataclass(slots=True)
-class _Pending:
-    """Per-request routing state while a quantum is in flight."""
-
-    idx: int                             # position in the input quantum
-    req: QuantumRequest
-    legs: list[tuple[int, RouteEntry]]   # (declared position, leg)
-    leg_ptr: int = 0
-    first_reason: Optional[DenyReason] = None
-    first_priority: float = 0.0
-    best_retry: Optional[float] = None
-
-    def current(self) -> tuple[int, RouteEntry]:
-        return self.legs[self.leg_ptr]
-
-    def note_denial(self, reason: Optional[DenyReason], priority: float,
-                    retry: Optional[float]) -> None:
-        if self.first_reason is None:
-            self.first_reason = reason
-            self.first_priority = priority
-        if retry is not None:
-            self.best_retry = (retry if self.best_retry is None
-                               else min(self.best_retry, retry))
-
-
 class Gateway:
     def __init__(self, pools: PoolOrManager,
                  store: Optional[StateStore] = None,
@@ -161,12 +139,6 @@ class Gateway:
         if self.telemetry is not None:
             for pool in self.manager.pools.values():
                 self.telemetry.attach_pool(pool)
-        #: public knob: False forces ``handle_quantum`` through the
-        #: generic leg-round loop even when the single-leg fast path
-        #: would apply — the chaos differential-replay harness runs the
-        #: same seeded scenario with this on/off (and against the
-        #: scalar ``handle``) to pin all three decision traces equal
-        self.quantum_fast_enabled: bool = True
 
     # -- back-compat accessors -------------------------------------------------
     @property
@@ -327,16 +299,20 @@ class Gateway:
     def handle_quantum(self, requests: Sequence[QuantumRequest],
                        now: float) -> list[GatewayResponse]:
         """Admit one scheduling quantum of requests through the fused
-        kernel — ONE ``admit_quantum`` dispatch per (pool, leg-round)
+        kernel — ONE ``admit_quantum`` dispatch per (pool, leg round)
         instead of five Python checks per request.
 
-        Round ``k`` groups every still-undecided request by the pool of
-        the ``k``-th leg of its ``route_order``; each pool is
-        snapshotted once (a pure read), its group replayed through the
-        kernel in arrival order, and the resulting charges/denials are
-        scattered back through the real ledger + pool bookkeeping.
-        Requests denied at round ``k`` re-enter round ``k+1`` with
-        their next leg.  Responses come back in input order.
+        Requests group per distinct (key, token shape), and each
+        group's route is resolved once.  Round ``k`` sends every
+        still-undecided request to the pool of its ``k``-th live leg
+        (a group's requests share their legs, so a group moves from
+        round to round together); each pool is snapshotted once, its
+        batch replayed through the kernel in arrival order, and the
+        resulting charges/denials scattered back through the real
+        ledger + pool bookkeeping.  A request denied at round ``k``
+        re-enters round ``k+1`` with its next leg; one denied at its
+        last leg is answered in that batch.  Responses come back in
+        input order.
 
         Parity contract (pinned by ``tests/test_gateway_quantum.py``):
         each pool decides its batch exactly as the scalar
@@ -365,95 +341,96 @@ class Gateway:
                     q.api_key, q.request_id, q.input_tokens, q.max_tokens,
                     now, kv_bytes_per_token=q.kv_bytes_per_token)]
             else:
-                responses = (self._quantum_fast(requests, now)
-                             if self.quantum_fast_enabled else None)
-                if responses is None:
-                    responses = self._quantum_rounds(requests, now)
+                responses = self._leg_rounds(requests, now)
         if tel is not None:
             tel.on_quantum(len(requests))
         return responses
 
     @hot_path
-    def _quantum_rounds(self, requests: Sequence[QuantumRequest],
-                        now: float) -> list[GatewayResponse]:
-        """The generic leg-round loop of :meth:`handle_quantum`."""
+    def _leg_rounds(self, requests: Sequence[QuantumRequest],
+                    now: float) -> list[GatewayResponse]:
+        """The leg rounds of :meth:`handle_quantum`.  Where some route
+        has several live legs each round is a ``gateway.round`` span;
+        otherwise the one round's pool batches sit directly under the
+        quantum."""
         tel = self.telemetry
         responses: list[Optional[GatewayResponse]] = [None] * len(requests)
         with child("gateway.route"):
-            # Routes are resolved once per distinct (key, token shape)
-            # at quantum start — within a quantum `now` is fixed, so a
-            # key's route (and its headroom ordering) is a constant.
-            route_cache: dict[tuple, Optional[list]] = {}
-            pending: list[_Pending] = []
-            unknown_ids: list[str] = []
+            # within a quantum `now` is fixed, so a key's route (and
+            # its headroom ordering) is a constant per token shape
+            by_ck: dict[tuple, list[int]] = {}
             for i, q in enumerate(requests):
                 ck = (q.api_key, q.input_tokens, q.max_tokens)
-                legs = route_cache.get(ck, False)
-                if legs is False:
-                    route = self.route(q.api_key, now)
-                    legs = None if route is None else \
-                        self.manager.route_order_indexed(
-                            list(route), q.input_tokens, q.max_tokens,
-                            now, policy=self.spill_policy)
-                    route_cache[ck] = legs
+                try:
+                    by_ck[ck].append(i)
+                except KeyError:
+                    by_ck[ck] = [i]
+            #: (undecided members in arrival order, key, input, max, legs)
+            groups: list[tuple] = []
+            unknown_ids: list[str] = []
+            unroutable_ids: list[str] = []
+            unroutable_incr: dict[str, float] = {}
+            for (key, inp, mx), idxs in by_ck.items():
+                route = self.route(key, now)
+                legs = None if route is None else \
+                    self.manager.route_order_indexed(
+                        list(route), inp, mx, now,
+                        policy=self.spill_policy)
                 if legs is None:
-                    responses[i] = GatewayResponse(
-                        status=401, request_id=q.request_id,
-                        reason="unknown_key")
-                    unknown_ids.append(q.request_id)
-                    continue
-                pending.append(_Pending(idx=i, req=q, legs=list(legs)))
-        if tel is not None and unknown_ids:
+                    for i in idxs:
+                        responses[i] = GatewayResponse(
+                            status=401, request_id=requests[i].request_id,
+                            reason="unknown_key")
+                        unknown_ids.append(requests[i].request_id)
+                elif not legs:               # route exists, no live pool
+                    for i in idxs:
+                        responses[i] = GatewayResponse(
+                            status=429, request_id=requests[i].request_id,
+                            retry_after_s=5.0,
+                            reason=DenyReason.POOL_UNAVAILABLE.value)
+                        unroutable_ids.append(requests[i].request_id)
+                    unroutable_incr[f"unroutable:{key}"] = \
+                        unroutable_incr.get(f"unroutable:{key}", 0.0) \
+                        + float(len(idxs))
+                else:
+                    groups.append((idxs, key, inp, mx, legs))
+        if unroutable_incr or unknown_ids:
             with child("gateway.record"):
-                tel.record_terminal(now, unknown_ids,
-                                    flightrec.VERDICT_UNKNOWN_KEY,
-                                    flightrec.REASON_NONE)
-
+                if unroutable_incr:
+                    self.store.incr_many(unroutable_incr, now)
+                if tel is not None:
+                    if unknown_ids:
+                        tel.record_terminal(now, unknown_ids,
+                                            flightrec.VERDICT_UNKNOWN_KEY,
+                                            flightrec.REASON_NONE)
+                    if unroutable_ids:
+                        tel.record_terminal(
+                            now, unroutable_ids, flightrec.VERDICT_DENY,
+                            flightrec.REASON_POOL_UNAVAILABLE)
+        multi_leg = any(len(g[4]) > 1 for g in groups)
+        #: request index → (first reason, its priority, least
+        #: Retry-After) for requests denied with a leg still to try
+        carry: dict[int, tuple] = {}
         k = 0
-        while pending:
-            # spills from different pools (and espec-miss skips) land in
-            # group order — restore arrival order so every pool batch
-            # replays its requests exactly as the scalar loop would
-            pending.sort(key=lambda p: p.idx)
-            done = [p for p in pending if p.leg_ptr >= len(p.legs)]
-            if done:
-                with child("gateway.deny"):
-                    for p in done:
-                        responses[p.idx] = self._finish_denied(p, now)
-            groups: dict[str, list[_Pending]] = {}
-            for p in pending:
-                if p.leg_ptr < len(p.legs):
-                    groups.setdefault(p.current()[1].pool, []).append(p)
-            pending = []
-            if not groups:
-                break
-            with child("gateway.round", index=k):
-                for pool_name, batch in groups.items():
-                    pending.extend(self._admit_batch(pool_name, batch,
-                                                     responses, now))
+        while groups:
+            batches: dict[str, list[tuple]] = {}
+            for g in groups:
+                batches.setdefault(g[4][k][1].pool, []).append(g)
+            # a round's pools dispatch in the order of their earliest
+            # request, as the sequential walk reaches them
+            order = sorted(batches,
+                           key=lambda p: min(g[0][0] for g in batches[p]))
+            groups = []
+            with (child("gateway.round", index=k) if multi_leg
+                  else contextlib.nullcontext()):
+                for pool_name in order:
+                    batch = batches[pool_name]
+                    left = self._admit_batch(pool_name, batch, k, requests,
+                                             responses, carry, now)
+                    groups.extend((idxs,) + g[1:]
+                                  for g, idxs in zip(batch, left) if idxs)
             k += 1
         return responses
-
-    def _finish_denied(self, p: _Pending, now: float) -> GatewayResponse:
-        """Route exhausted: the 429 (same attribution as ``handle``)."""
-        if p.legs:
-            self.store.incr(f"denials:{p.legs[0][1].entitlement}",
-                            1.0, now)
-        else:
-            self.store.incr(f"unroutable:{p.req.api_key}", 1.0, now)
-        if p.first_reason is None:         # no live pool on the route
-            if self.telemetry is not None:
-                self.telemetry.record_terminal_one(
-                    now, p.req.request_id, flightrec.VERDICT_DENY,
-                    flightrec.REASON_POOL_UNAVAILABLE)
-            return GatewayResponse(
-                status=429, request_id=p.req.request_id,
-                retry_after_s=5.0,
-                reason=DenyReason.POOL_UNAVAILABLE.value)
-        return GatewayResponse(
-            status=429, request_id=p.req.request_id,
-            retry_after_s=p.best_retry, reason=p.first_reason.value,
-            priority=p.first_priority)
 
     @hot_path
     def _dispatch_admit(self, pool: TokenPool, snap, rows, tokens, kvs,
@@ -461,7 +438,7 @@ class Gateway:
                                          np.ndarray]:
         """ONE padded ``admit_quantum`` dispatch for a pool batch of
         ``m`` live requests in replay order (``rows``/``tokens``/
-        ``kvs`` may be lists or arrays).  Returns host-side
+        ``kvs`` arrays).  Returns host-side
         (admitted, reasons, weights) trimmed to the live prefix: the
         ``gateway.admit`` span."""
         with child("gateway.admit", pool.spec.name):
@@ -507,100 +484,22 @@ class Gateway:
                     readback(req_w)[:m])
 
     @hot_path
-    def _quantum_fast(self, requests: Sequence[QuantumRequest],
-                      now: float) -> Optional[list[GatewayResponse]]:
-        """Array-native quantum for ALL-single-leg route sets — the
-        dominant deployment shape, where every key resolves to exactly
-        one live leg, a denial is terminal, and no leg-round loop is
-        needed.
+    def _admit_batch(self, pool_name: str, groups: list[tuple], k: int,
+                     requests: Sequence[QuantumRequest], responses: list,
+                     carry: dict, now: float) -> list[list[int]]:
+        """One pool's batch of leg round ``k``: snapshot → kernel →
+        batched scatter.  ``groups`` are the (members, key, input, max,
+        legs) groups whose ``k``-th live leg is on this pool; every
+        member of a group shares its row, tokens and hop, so group
+        constants are gathered into per-request arrays in arrival
+        order.
 
-        Requests group per distinct (key, token shape): routes resolve
-        once per group, group constants (row, tokens, hop) expand to
-        request arrays with ``np.full``, and each pool batch runs the
-        SAME padded kernel dispatch and batched row-op scatters as the
-        generic path — so per-request Python shrinks to one response
-        tuple plus id extraction.  Decision/state parity with the
-        generic leg-round loop is pinned by
-        ``tests/test_gateway_quantum.py``.
-
-        Returns None — before touching ANY state — when some key's
-        route has several live legs; the generic loop takes over."""
-        n = len(requests)
-        tel = self.telemetry
-        with child("gateway.route"):
-            by_ck: dict[tuple, list[int]] = {}
-            for i, q in enumerate(requests):
-                ck = (q.api_key, q.input_tokens, q.max_tokens)
-                try:
-                    by_ck[ck].append(i)
-                except KeyError:
-                    by_ck[ck] = [i]
-            # resolve every distinct key first — pure reads, so the
-            # multi-leg bail-out leaves no partial state behind
-            resolved = []
-            for ck, idxs in by_ck.items():
-                key, inp, mx = ck
-                route = self.route(key, now)
-                legs = None if route is None else \
-                    self.manager.route_order_indexed(
-                        list(route), inp, mx, now,
-                        policy=self.spill_policy)
-                if legs is not None and len(legs) > 1:
-                    return None
-                resolved.append((idxs, ck, legs))
-            responses: list[Optional[GatewayResponse]] = [None] * n
-            pools: dict[str, list] = {}
-            unknown_ids: list[str] = []
-            unroutable_ids: list[str] = []
-            unroutable_incr: dict[str, float] = {}
-            for idxs, ck, legs in resolved:
-                key, inp, mx = ck
-                if legs is None:
-                    for i in idxs:
-                        responses[i] = GatewayResponse(
-                            status=401, request_id=requests[i].request_id,
-                            reason="unknown_key")
-                        unknown_ids.append(requests[i].request_id)
-                elif not legs:               # route exists, no live pool
-                    for i in idxs:
-                        responses[i] = GatewayResponse(
-                            status=429, request_id=requests[i].request_id,
-                            retry_after_s=5.0,
-                            reason=DenyReason.POOL_UNAVAILABLE.value)
-                        unroutable_ids.append(requests[i].request_id)
-                    unroutable_incr[f"unroutable:{key}"] = \
-                        unroutable_incr.get(f"unroutable:{key}", 0.0) \
-                        + float(len(idxs))
-                else:
-                    hop, leg = legs[0]
-                    pools.setdefault(leg.pool, []).append(
-                        (idxs, key, leg.entitlement, inp, mx, hop))
-        if unroutable_incr or unknown_ids:
-            with child("gateway.record"):
-                if unroutable_incr:
-                    self.store.incr_many(unroutable_incr, now)
-                if tel is not None:
-                    if unknown_ids:
-                        tel.record_terminal(now, unknown_ids,
-                                            flightrec.VERDICT_UNKNOWN_KEY,
-                                            flightrec.REASON_NONE)
-                    if unroutable_ids:
-                        tel.record_terminal(
-                            now, unroutable_ids, flightrec.VERDICT_DENY,
-                            flightrec.REASON_POOL_UNAVAILABLE)
-        for pool_name, entries in pools.items():
-            self._admit_batch_fast(pool_name, entries, requests,
-                                   responses, now)
-        return responses
-
-    @hot_path
-    def _admit_batch_fast(self, pool_name: str, entries: list,
-                          requests: Sequence[QuantumRequest],
-                          responses: list, now: float) -> None:
-        """One pool's single-leg quantum batch: snapshot → kernel →
-        batched scatter, exactly like ``_admit_batch``, but built from
-        per-group constants (every request of a (key, shape) group
-        shares its row/tokens/hop) stitched back into arrival order."""
+        A denial at a group's last leg is answered here, as
+        :meth:`handle` answers an exhausted route: the first denial's
+        reason and priority, the least Retry-After over the legs
+        tried, counted on the first live leg's entitlement.  Returns,
+        per group, the members denied with a leg still to try, in
+        arrival order (their state waits in ``carry``)."""
         pool = self.manager.pool(pool_name)
         snap = quantum_snapshot(pool, now)
         row_of = snap.row_of
@@ -611,46 +510,59 @@ class Gateway:
         #: ``incr_many`` (the Redis pipeline shape) instead of one
         #: ``incr`` per key
         incr_acc: dict[str, float] = {}
-        # NOT_BOUND skips never reach the kernel; their decision rows
-        # record with ent_slot -1 and zeroed state dims
-        nb_rids: list[str] = []
-        nb_hops: list[int] = []
-        nb_toks: list[float] = []
+        left: list[list[int]] = [[] for _ in groups]
+
+        def deny(i, rid, reason, priority, retry, gid):
+            prior = carry.pop(i, None) if carry else None
+            if prior is not None:
+                reason, priority = prior[0], prior[1]
+                if prior[2] is not None:
+                    retry = prior[2] if retry is None \
+                        else min(prior[2], retry)
+            legs = groups[gid][4]
+            if k + 1 < len(legs):
+                carry[i] = (reason, priority, retry)
+                left[gid].append(i)
+                return
+            k_den = f"denials:{legs[0][1].entitlement}"
+            incr_acc[k_den] = incr_acc.get(k_den, 0.0) + 1.0
+            responses[i] = GatewayResponse(
+                status=429, request_id=rid, retry_after_s=retry,
+                reason=reason.value, priority=priority)
+
         g_ent: list[str] = []
-        g_key: list[str] = []
         g_hop: list[int] = []
         g_row: list[int] = []
         g_tok: list[float] = []
         g_inp: list[int] = []
         g_mt: list[int] = []
         counts: list[int] = []
-        idx_lists: list[list[int]] = []
-        for idxs, key, ent, inp, mx, hop in entries:
-            row = row_of.get(ent)
+        # NOT_BOUND skips never reach the kernel; their decision rows
+        # record with ent_slot -1 and zeroed state dims
+        nb_rids: list[str] = []
+        nb_hops: list[int] = []
+        nb_toks: list[float] = []
+        for gid, (idxs, _, inp, mx, legs) in enumerate(groups):
+            hop, leg = legs[k]
+            row = row_of.get(leg.entitlement)
             mt = mx if mx is not None else default_mt
-            if row is None:
-                # the scalar pipeline's espec-is-None early out:
-                # terminal NOT_BOUND without touching pool state
-                for i in idxs:
-                    responses[i] = GatewayResponse(
-                        status=429, request_id=requests[i].request_id,
-                        reason=DenyReason.NOT_BOUND.value)
-                    if tel is not None:
-                        nb_rids.append(requests[i].request_id)
-                        nb_hops.append(hop)
-                        nb_toks.append(float(inp + mt))
-                incr_acc[f"denials:{ent}"] = \
-                    incr_acc.get(f"denials:{ent}", 0.0) + float(len(idxs))
-                continue
-            g_ent.append(ent)
-            g_key.append(key)
+            g_ent.append(leg.entitlement)
             g_hop.append(hop)
-            g_row.append(row)
+            g_row.append(-1 if row is None else row)
             g_tok.append(float(inp + mt))
             g_inp.append(inp)
             g_mt.append(mt)
-            counts.append(len(idxs))
-            idx_lists.append(idxs)
+            counts.append(0 if row is None else len(idxs))
+            if row is None:
+                # the scalar pipeline's espec-is-None early out: a
+                # NOT_BOUND denial without touching pool state
+                for i in idxs:
+                    rid = requests[i].request_id
+                    deny(i, rid, DenyReason.NOT_BOUND, 0.0, None, gid)
+                    if tel is not None:
+                        nb_rids.append(rid)
+                        nb_hops.append(hop)
+                        nb_toks.append(float(inp + mt))
         if tel is not None and nb_rids:
             with child("gateway.record", pool_name):
                 tel.record_decisions(
@@ -662,22 +574,24 @@ class Gateway:
                     0.0, float(snap.running_min_priority)
                     * (1.0 - pool.spec.admission_slack),
                     np.asarray(nb_toks, np.float64))
-        if not counts:
+        cnt = np.asarray(counts, np.int64)
+        m = int(cnt.sum())
+        if not m:
             if incr_acc:
                 with child("gateway.record", pool_name):
                     store.incr_many(incr_acc, now)
-            return
+            return left
         # per-group constants expand to per-request arrays by GATHER,
         # not per-group np.full loops; argsort restores arrival order
-        cnt = np.asarray(counts, np.int64)
-        m = int(cnt.sum())
-        idx_cat = np.fromiter(chain.from_iterable(idx_lists),
-                              np.int64, count=m)
+        idx_cat = np.fromiter(
+            chain.from_iterable(g[0] for g, c in zip(groups, counts) if c),
+            np.int64, count=m)
         order = np.argsort(idx_cat)
         idx_arr = idx_cat[order]
-        gids = np.repeat(np.arange(len(counts), dtype=np.int64),
+        gids = np.repeat(np.arange(len(groups), dtype=np.int64),
                          cnt)[order]
-        rows64 = np.asarray(g_row, np.int64)[gids]
+        g_row_arr = np.asarray(g_row, np.int64)
+        rows64 = g_row_arr[gids]
         toks64 = np.asarray(g_tok, np.float64)[gids]
         inps = np.asarray(g_inp, np.int64)[gids]
         mts = np.asarray(g_mt, np.int64)[gids]
@@ -699,6 +613,8 @@ class Gateway:
         admitted, reasons, req_w = self._dispatch_admit(
             pool, snap, rows64, toks64, kvs64, m)
 
+        #: admits off a later leg than the first, by the first leg's pool
+        spilled_from: dict[str, int] = {}
         with child("gateway.charge", pool_name):
             ledger = pool.ledger
             js = np.flatnonzero(admitted)
@@ -706,12 +622,14 @@ class Gateway:
             ch_slots = np.empty(0, np.int64)
             charge_ids: list[str] = []
             if js.size:
-                # buckets ensured once per group with kernel admits (the
-                # same entitlement set the generic pass-1 loop ensures),
+                # buckets ensured once per group with kernel admits,
                 # vectorized: rates come off the eff_tps column, with the
-                # scalar path's spec-f64 baseline on the eff==0 fallback
+                # scalar path's spec-f64 baseline on the eff==0 fallback;
+                # the ledger re-checks every charge (it stays
+                # authoritative if f32/f64 disagree on an exact budget
+                # boundary — those flip to budget denials below)
                 ub = np.unique(gids[js])
-                uslots = np.asarray(g_row, np.int64)[ub]
+                uslots = g_row_arr[ub]
                 rates = pool.store.col["eff_tps"][uslots].copy()
                 for t in np.flatnonzero(rates == 0.0).tolist():
                     rates[t] = pool.entitlements[
@@ -730,27 +648,40 @@ class Gateway:
             if acc.size:
                 admit_ids = charge_ids if acc.size == js.size else \
                     [rids[t] for t in acc.tolist()]
-                pool.admit_rows(admit_ids, rows64[acc], kvs64[acc],
-                                toks64[acc], now, slots=ch_slots)
+                # ch_slots aligns with the accepted charges in charge
+                # order — exactly this admit batch
+                slots = pool.admit_rows(admit_ids, rows64[acc], kvs64[acc],
+                                        toks64[acc], now, slots=ch_slots)
+                if k:
+                    # served off a later leg than the first: the rows
+                    # remember the first live leg, whose debt their
+                    # completion transfers (PoolManager.transfer_spill_debt)
+                    spill_col = pool.table.spill_from
+                    for s, gid in zip(slots.tolist(), gids[acc].tolist()):
+                        first = groups[gid][4][0][1]
+                        spill_col[s] = (first.pool, first.entitlement)
                 # demand lands exactly like the scalar register_admit
                 # loop: one unbuffered index-ordered f64 add chain
                 np.add.at(pool.store.col["demand_window"], rows64[acc],
                           toks64[acc])
-                per_gid = np.bincount(gids[acc], minlength=len(g_ent))
-                for gid, cnt in enumerate(per_gid.tolist()):
-                    if cnt:
+                per_gid = np.bincount(gids[acc], minlength=len(groups))
+                for gid, n_adm in enumerate(per_gid.tolist()):
+                    if n_adm:
                         k_adm = f"admits:{g_ent[gid]}"
                         incr_acc[k_adm] = incr_acc.get(k_adm, 0.0) \
-                            + float(cnt)
+                            + float(n_adm)
                         if g_hop[gid] > 0:
-                            k_sp = f"spills:{g_key[gid]}"
+                            k_sp = f"spills:{groups[gid][1]}"
                             incr_acc[k_sp] = incr_acc.get(k_sp, 0.0) \
-                                + float(cnt)
+                                + float(n_adm)
+                            src = groups[gid][4][0][1].pool
+                            spilled_from[src] = \
+                                spilled_from.get(src, 0) + n_adm
                 if acc.size == m:
                     it = zip(idx_l, rids, w_l, gid_l)
                 else:
-                    it = ((idx_l[k], rids[k], w_l[k], gid_l[k])
-                          for k in acc.tolist())
+                    it = ((idx_l[j], rids[j], w_l[j], gid_l[j])
+                          for j in acc.tolist())
                 # tuple.__new__ skips the NamedTuple default-filling
                 # wrapper — measurably faster at 10^5 responses/quantum
                 mk = tuple.__new__
@@ -759,6 +690,12 @@ class Gateway:
                                       (200, rid, None, None, w, pool_name,
                                        g_ent[gid], g_hop[gid]))
 
+        # Denials run AFTER the batch's admits are registered, so
+        # Retry-After hints reflect the pool the retrying client will
+        # actually face (the scalar loop's hints see only the admits
+        # that preceded each request).  Bookkeeping lands as ONE
+        # ``register_deny_batch`` scatter, and hints are memoized per
+        # (reason, entitlement, tokens) — see ``_deny_hint``.
         den = np.flatnonzero(~charged)
         if den.size:
             with child("gateway.deny", pool_name):
@@ -769,33 +706,28 @@ class Gateway:
                 adm_kernel = admitted.tolist()
                 reasons_l = reasons.tolist()
                 toks_l = toks64.tolist()
-                dcount: dict[str, int] = {}
-                for d, k in enumerate(den.tolist()):
-                    ent = g_ent[gid_l[k]]
-                    w = w_l[k]
-                    code = 3 if adm_kernel[k] else int(reasons_l[k])
+                for d, j in enumerate(den.tolist()):
+                    gid = gid_l[j]
+                    ent = g_ent[gid]
+                    w = w_l[j]
+                    code = 3 if adm_kernel[j] else int(reasons_l[j])
                     reason = _REASON_CODES[code]
                     retry = self._deny_hint(pool, pool_name, ent, reason,
-                                            toks_l[k], w, now,
+                                            toks_l[j], w, now,
                                             cache=hint_cache)
                     deny_ents.append(ent)
                     if reason is not DenyReason.NOT_BOUND:
-                        deny_demand[d] = toks_l[k]
+                        deny_demand[d] = toks_l[j]
                     lp = reason is DenyReason.LOW_PRIORITY
                     deny_lp[d] = lp
-                    dcount[ent] = dcount.get(ent, 0) + 1
-                    responses[idx_l[k]] = GatewayResponse(
-                        status=429, request_id=rids[k],
-                        retry_after_s=retry, reason=reason.value,
-                        priority=w if lp else 0.0)
+                    deny(idx_l[j], rids[j], reason, w if lp else 0.0,
+                         retry, gid)
                 pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
-                for ent, cnt in dcount.items():
-                    k_den = f"denials:{ent}"
-                    incr_acc[k_den] = incr_acc.get(k_den, 0.0) + float(cnt)
         with child("gateway.record", pool_name):
             if incr_acc:
                 store.incr_many(incr_acc, now)
             if tel is not None:
+                tel.record_spill_admits(pool_name, spilled_from)
                 # ONE flight scatter for the kernel batch, with reasons
                 # finalized the way responses were: a kernel admit the
                 # ledger rejected flips to TOKEN_BUDGET (code 3)
@@ -811,222 +743,7 @@ class Gateway:
                     * (1.0 - pool.spec.admission_slack),
                     toks64,
                     levels_at=np.asarray(snap.bucket_level, np.float64))
-
-    @hot_path
-    def _admit_batch(self, pool_name: str, batch: list[_Pending],
-                     responses: list, now: float) -> list[_Pending]:
-        """One fused kernel dispatch for one pool's leg-round group;
-        scatters results into ``responses`` / pool state and returns
-        the requests that spill into the next round."""
-        pool = self.manager.pool(pool_name)
-        snap = quantum_snapshot(pool, now)
-        spilled: list[_Pending] = []
-
-        # Legs naming an entitlement the pool has never heard of deny
-        # NOT_BOUND without touching pool state (the scalar pipeline's
-        # espec-is-None early out) — they skip the kernel entirely.
-        kernel_batch: list[_Pending] = []
-        tel = self.telemetry
-        nb_rids: list[str] = []
-        nb_hops: list[int] = []
-        nb_toks: list[float] = []
-        #: declared route position per kernel-batch entry, captured
-        #: BEFORE the denial pass advances leg_ptr
-        hops: list[int] = []
-        rows, tokens, kvs, eff_max = [], [], [], []
-        for p in batch:
-            hop, leg = p.current()
-            row = snap.row_of.get(leg.entitlement)
-            mt = (p.req.max_tokens if p.req.max_tokens is not None
-                  else pool.spec.default_max_tokens)
-            if row is None:
-                if tel is not None:
-                    nb_rids.append(p.req.request_id)
-                    nb_hops.append(hop)
-                    nb_toks.append(float(p.req.input_tokens + mt))
-                p.note_denial(DenyReason.NOT_BOUND, 0.0, None)
-                p.leg_ptr += 1
-                spilled.append(p)
-                continue
-            kernel_batch.append(p)
-            hops.append(hop)
-            rows.append(row)
-            tokens.append(float(p.req.input_tokens + mt))
-            kvs.append(float(p.req.input_tokens + mt)
-                       * p.req.kv_bytes_per_token)
-            eff_max.append(mt)
-        if tel is not None and nb_rids:
-            with child("gateway.record", pool_name):
-                tel.record_decisions(
-                    pool_name, now, nb_rids,
-                    np.full(len(nb_rids), -1, np.int64),
-                    np.asarray(nb_hops, np.int64),
-                    np.zeros(len(nb_rids), bool),
-                    np.full(len(nb_rids), 1, np.int16),   # NOT_BOUND
-                    0.0, float(snap.running_min_priority)
-                    * (1.0 - pool.spec.admission_slack),
-                    np.asarray(nb_toks, np.float64))
-        if not kernel_batch:
-            return spilled
-
-        m = len(kernel_batch)
-        admitted, reasons, req_w = self._dispatch_admit(
-            pool, snap, rows, tokens, kvs, m)
-
-        incr_acc: dict[str, float] = {}
-        #: admits off a later leg than the first, by the first leg's pool
-        spilled_from: dict[str, int] = {}
-        with child("gateway.charge", pool_name):
-            # -- scatter, pass 1: the quantum's charges, in replay order —
-            # array-native: no per-request ``Charge`` objects, accepted
-            # charges land as batched request-table column writes
-            # (``Ledger.charge_rows``).  Buckets are ensured once per
-            # entitlement; the ledger re-checks every charge (it stays
-            # authoritative if f32/f64 disagree on an exact budget
-            # boundary — those flip to budget denials below).
-            ledger = pool.ledger
-            slot_of = pool.store.slot_of
-            ensured: set = set()
-            charge_js: list[int] = []
-            charge_ids: list[str] = []
-            ent_slots: list[int] = []
-            inp_toks: list[int] = []
-            max_toks: list[int] = []
-            for j, p in enumerate(kernel_batch):
-                if not admitted[j]:
-                    continue
-                ent = p.current()[1].entitlement
-                if ent not in ensured:
-                    st = pool.status[ent]
-                    ledger.ensure(
-                        ent, st.effective.tokens_per_second
-                        or pool.entitlements[ent].baseline.tokens_per_second,
-                        now)
-                    ensured.add(ent)
-                charge_js.append(j)
-                charge_ids.append(p.req.request_id)
-                ent_slots.append(slot_of[ent])
-                inp_toks.append(p.req.input_tokens)
-                max_toks.append(int(eff_max[j]))
-            tokens64 = np.asarray(tokens, np.float64)
-            kvs64 = np.asarray(kvs, np.float64)
-            charged = np.zeros(m, bool)
-            js = np.asarray(charge_js, np.int64)
-            owners = np.asarray(ent_slots, np.int64)
-            ch_slots = np.empty(0, np.int64)
-            if charge_js:
-                ok, ch_slots = ledger.charge_rows(
-                    charge_ids, owners, tokens64[js],
-                    np.asarray(inp_toks, np.int64),
-                    np.asarray(max_toks, np.int64), now)
-                charged[js] = ok
-
-            # -- scatter, pass 2a: admits.  ONE ``admit_rows`` column
-            # scatter — no per-request ``InFlight`` objects — and counter
-            # increments are aggregated: the StateStore and store columns
-            # are hit once per distinct key per quantum, not per request.
-            acc = np.flatnonzero(charged[js]) if charge_js else js
-            if acc.size:
-                n_admits: dict = {}
-                n_spills: dict = {}
-                demand: dict = {}
-                # (row slot index in this admit batch, preferred leg) for
-                # requests served off a spill leg — tagged on the new rows
-                # below for completion-time debt transfer
-                spill_tags: list[tuple[int, tuple[str, str]]] = []
-                acc_l = acc.tolist()
-                for k, i in enumerate(acc_l):
-                    p = kernel_batch[charge_js[i]]
-                    hop, leg = p.current()
-                    ent = leg.entitlement
-                    w = float(req_w[charge_js[i]])
-                    demand[ent] = demand.get(ent, 0.0) \
-                        + float(tokens[charge_js[i]])
-                    n_admits[ent] = n_admits.get(ent, 0) + 1
-                    if hop > 0:
-                        key = p.req.api_key
-                        n_spills[key] = n_spills.get(key, 0) + 1
-                        src = p.legs[0][1].pool
-                        spilled_from[src] = spilled_from.get(src, 0) + 1
-                    if p.leg_ptr > 0:
-                        first = p.legs[0][1]
-                        spill_tags.append((k, (first.pool,
-                                               first.entitlement)))
-                    responses[p.idx] = GatewayResponse(
-                        status=200, request_id=p.req.request_id,
-                        priority=w, pool=pool_name, entitlement=ent,
-                        spill_hops=hop)
-                js_acc = js[acc]
-                # ch_slots aligns with the accepted subset of the charge
-                # batch in charge order — exactly this admit batch, so the
-                # rows charged are the rows admitted (no second id lookup)
-                slots = pool.admit_rows(
-                    [charge_ids[i] for i in acc_l], owners[acc],
-                    kvs64[js_acc], tokens64[js_acc], now,
-                    demand_tokens=demand, slots=ch_slots)
-                spill_col = pool.table.spill_from
-                for k, leg_from in spill_tags:
-                    spill_col[int(slots[k])] = leg_from
-                incr_acc = {f"admits:{ent}": float(cnt)
-                            for ent, cnt in n_admits.items()}
-                for key, cnt in n_spills.items():
-                    incr_acc[f"spills:{key}"] = float(cnt)
-
-        # -- scatter, pass 2b: denials.  Runs AFTER the quantum's
-        # admits are registered, so Retry-After hints reflect the pool
-        # the retrying client will actually face (the scalar loop's
-        # hints see only the admits that preceded each request).
-        # Bookkeeping lands as ONE ``register_deny_batch`` scatter, and
-        # hints are memoized per (reason, entitlement, tokens): a
-        # denial mutates only demand/denial counters, which no hint
-        # formula reads, so within one batch equal keys give equal
-        # hints — and the priority threshold (a pool-wide Eq. 1 min)
-        # is evaluated at most once per batch.
-        deny_js = np.flatnonzero(~charged)
-        if deny_js.size:
-            with child("gateway.deny", pool_name):
-                hint_cache: dict = {}
-                deny_ents: list[str] = []
-                deny_demand = np.zeros(deny_js.size, np.float64)
-                deny_lp = np.zeros(deny_js.size, bool)
-                for k, j in enumerate(deny_js.tolist()):
-                    p = kernel_batch[j]
-                    ent = p.current()[1].entitlement
-                    w = float(req_w[j])
-                    code = 3 if admitted[j] else int(reasons[j])
-                    reason = _REASON_CODES[code]
-                    retry = self._deny_hint(pool, pool_name, ent, reason,
-                                            float(tokens[j]), w, now,
-                                            cache=hint_cache)
-                    deny_ents.append(ent)
-                    if reason is not DenyReason.NOT_BOUND:
-                        deny_demand[k] = float(tokens[j])
-                    deny_lp[k] = reason is DenyReason.LOW_PRIORITY
-                    p.note_denial(reason,
-                                  w if reason is DenyReason.LOW_PRIORITY
-                                  else 0.0, retry)
-                    p.leg_ptr += 1
-                    spilled.append(p)
-                pool.register_deny_batch(deny_ents, deny_demand, deny_lp)
-        with child("gateway.record", pool_name):
-            if incr_acc:
-                self.store.incr_many(incr_acc, now)
-            if tel is not None:
-                tel.record_spill_admits(pool_name, spilled_from)
-                final_reasons = np.where(
-                    charged, 0,
-                    np.where(admitted, 3, reasons.astype(np.int64)))
-                tel.record_decisions(
-                    pool_name, now,
-                    [p.req.request_id for p in kernel_batch],
-                    np.asarray(rows, np.int64), np.asarray(hops, np.int64),
-                    charged, final_reasons.astype(np.int16),
-                    np.asarray(req_w, np.float64),
-                    float(snap.running_min_priority)
-                    * (1.0 - pool.spec.admission_slack),
-                    tokens64,
-                    levels_at=np.asarray(snap.bucket_level, np.float64))
-        return spilled
+        return left
 
     def _deny_hint(self, pool: TokenPool, pool_name: str, ent: str,
                    reason: DenyReason, tokens: float, w: float,

@@ -19,8 +19,9 @@ nest::
 
     gateway.quantum   handle_quantum (root)
       gateway.route     key -> legs: store reads, route_order_indexed
-      gateway.round     one leg round of the generic path (its index);
-                        the snapshot .. record of each pool batch in it
+      gateway.round     one leg round, where the quantum holds a
+                        multi-leg route (its index); the snapshot ..
+                        record of each pool batch in it
       gateway.snapshot  quantum_snapshot: uploads, priority_batch, the
                         owner mask and owner_min up to its readback
       gateway.admit     padding, upload, admit_quantum, readback

@@ -11,8 +11,9 @@ decision time — enough to answer "why was request X denied at t=42.3"
 without replaying the simulation.
 
 Writes are batched: the gateway emits ONE ``record_batch`` call per
-``admit_quantum`` / ``_quantum_fast`` dispatch (a masked scatter per
-column into ring positions ``(head + arange(m)) & (cap-1)``); the
+``admit_quantum`` dispatch of ``Gateway.handle_quantum`` (a masked
+scatter per column into ring positions ``(head + arange(m)) &
+(cap-1)``); the
 scalar ``record`` twin is the parity oracle and serves the scalar
 ``Gateway.handle`` path.  ``explain(request_id)`` reconstructs the
 full multi-leg decision narrative; ``recent(...)`` is the structured
@@ -155,7 +156,7 @@ class DecisionTrace:
     sweep in ``tests/test_telemetry.py``): admit anywhere → 200 with
     the admitting leg's pool/priority/hops; otherwise the FIRST
     denial's reason, with priority surfaced only for low-priority
-    denials — same as ``_Pending.note_denial``."""
+    denials — same as ``Gateway.handle``."""
 
     request_id: str
     legs: tuple[FlightRow, ...]

@@ -57,9 +57,10 @@ SPAN_NAMES = (
     "fleet.plan", "fleet.kernel", "fleet.rebalance",
     "compile",
 )
-#: the spans of leg routing and settlement: each leg round of the
-#: generic quantum (``gateway.round``, under ``gateway.quantum``;
-#: single-leg routes take the fast path, which has no rounds), the
+#: the spans of leg routing and settlement: each leg round of a
+#: quantum that holds a multi-leg route (``gateway.round``, under
+#: ``gateway.quantum``; with single-leg routes alone the one round's
+#: pool batches sit directly under the quantum), the
 #: settle of a batch of completions (``gateway.settle``, a root) and the
 #: debt transfers of its requests served on a spill leg
 #: (``pool.spill_debt``)

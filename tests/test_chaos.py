@@ -295,7 +295,7 @@ class TestDifferentialReplay:
     def test_mini_replay_identical(self):
         res = run_replay(MINI)
         assert res.identical, res.mismatches[:10]
-        assert set(res.traces) == {"scalar", "quantum", "quantum_fast"}
+        assert set(res.traces) == {"scalar", "quantum"}
         # the run produced real decisions, not an empty diff
         assert len(res.traces["scalar"].outcomes) > 10
         assert res.traces["scalar"].flight_legs

@@ -286,28 +286,33 @@ class TestDenialAttribution:
 
 
 class TestFastPathParity:
-    """All-single-leg route sets take ``Gateway._quantum_fast``; its
-    decisions, counters, bucket levels, and in-flight sets must match
-    the generic leg-round loop exactly (integer token values keep the
-    f64 bookkeeping bit-exact)."""
+    """The quantum path against the sequential scalar loop on route
+    sets the old single-leg fast path took (even seeds: one leg per
+    key, on any pool) and on mixed quanta (odd seeds: routes drawn as
+    prefixes of one pool order, one to three legs).  Both kinds hold a
+    dead pool, a leg naming an entitlement its pool never heard of
+    (on a non-last leg where routes have several), a route whose only
+    pool does not exist and an unknown key.  Retry-After is not
+    compared: the quantum's hints describe the pool after the quantum
+    (``Gateway._deny_hint``)."""
 
-    def _build(self, seed, fast):
+    def _build(self, seed):
         rng = random.Random(seed)
         mgr = PoolManager([
             mkpool("a", tps=rng.choice([300.0, 600.0]),
                    slots=rng.choice([2.0, 4.0])),
             mkpool("b", tps=600.0, slots=4.0),
+            mkpool("c", tps=1000.0, slots=8.0),
         ])
+        mgr.pool("c").set_replicas(0)
         classes = [ServiceClass.GUARANTEED, ServiceClass.ELASTIC,
                    ServiceClass.SPOT]
         gw = Gateway(mgr)
-        if not fast:
-            # force the generic leg-round loop
-            gw._quantum_fast = lambda requests, now: None
-        for k in range(5):
-            klass = classes[k % 3]
-            pname = rng.choice(["a", "b"])
-            ename = f"t{k}@{pname}"
+        order = ["a", "b", "c"]
+        rng.shuffle(order)
+        prefix = seed % 2 == 1
+
+        def add(pname, ename, klass):
             mgr.pool(pname).add_entitlement(ent(
                 ename, pname, klass=klass,
                 tps=rng.choice([80.0, 200.0]),
@@ -316,11 +321,20 @@ class TestFastPathParity:
             if klass is ServiceClass.SPOT:
                 mgr.pool(pname).ledger.set_rate(ename, 200.0, 0.0)
                 mgr.pool(pname).ledger.bucket(ename).level = 200.0
-            gw.register_route(f"k{k}", [(pname, ename)])
-        # a leg naming an entitlement the pool never heard of
-        # (espec-miss → terminal NOT_BOUND), and a route whose only
-        # pool does not exist (→ POOL_UNAVAILABLE + unroutable)
-        gw.register_route("kmiss", [("a", "ghost")])
+
+        for k in range(5):
+            klass = classes[k % 3]
+            pools = (order[:rng.randint(1, 3)] if prefix else
+                     rng.choices(["a", "b", "c"], weights=[2, 2, 1]))
+            for pname in pools:
+                add(pname, f"t{k}@{pname}", klass)
+            gw.register_route(f"k{k}", [(p, f"t{k}@{p}") for p in pools])
+        # espec-miss → NOT_BOUND, then the next leg where there is one
+        miss = [(order[0] if prefix else "a", "ghost")]
+        if prefix:
+            add(order[1], f"g@{order[1]}", ServiceClass.ELASTIC)
+            miss.append((order[1], f"g@{order[1]}"))
+        gw.register_route("kmiss", miss)
         gw.register_route("kdead", [("zpool", "ez")])
         keys = [f"k{i}" for i in range(5)] + ["kmiss", "kdead", "nokey"]
         reqs = [QuantumRequest(api_key=rng.choice(keys),
@@ -332,48 +346,71 @@ class TestFastPathParity:
 
     @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5, 6])
     def test_fast_matches_generic(self, seed):
-        gw_f, reqs = self._build(seed, fast=True)
-        gw_g, _ = self._build(seed, fast=False)
+        gw_q, reqs = self._build(seed)
+        gw_s, _ = self._build(seed)            # identical fresh state
 
-        fast = gw_f.handle_quantum(reqs, now=0.0)
-        generic = gw_g.handle_quantum(reqs, now=0.0)
-        for rf, rg in zip(fast, generic):
-            assert _resp_key(rf) == _resp_key(rg)
-            assert rf.request_id == rg.request_id
-            assert rf.retry_after_s == rg.retry_after_s
-            assert rf.priority == rg.priority
-        for pname in ["a", "b"]:
-            pf, pg = gw_f.manager.pool(pname), gw_g.manager.pool(pname)
-            assert sorted(pf.in_flight) == sorted(pg.in_flight)
-            assert set(pf.ledger._buckets) == set(pg.ledger._buckets)
-            for ename, bucket in pf.ledger._buckets.items():
-                assert bucket.level == pg.ledger.bucket(ename).level
-            assert list(pf.store.col["demand_window"][
-                pf.store.live_slots()]) == \
-                list(pg.store.col["demand_window"][
-                    pg.store.live_slots()])
-        keys = set(gw_f.store.keys()) | set(gw_g.store.keys())
+        quantum = gw_q.handle_quantum(reqs, now=0.0)
+        scalar = [gw_s.handle(q.api_key, q.request_id, q.input_tokens,
+                              q.max_tokens, now=0.0) for q in reqs]
+        for rq, rs in zip(quantum, scalar):
+            assert _resp_key(rq) == _resp_key(rs)
+            assert rq.request_id == rs.request_id
+            assert rq.priority == pytest.approx(rs.priority, rel=1e-5)
+        for pname in ["a", "b", "c"]:
+            pq, ps = gw_q.manager.pool(pname), gw_s.manager.pool(pname)
+            assert sorted(pq.in_flight) == sorted(ps.in_flight)
+            for ename, bucket in pq.ledger._buckets.items():
+                assert bucket.level == pytest.approx(
+                    ps.ledger.bucket(ename).level)
+            assert list(pq.store.col["demand_window"][
+                pq.store.live_slots()]) == pytest.approx(
+                list(ps.store.col["demand_window"][
+                    ps.store.live_slots()]))
+        keys = set(gw_q.store.keys()) | set(gw_s.store.keys())
         for key in keys:
             if key.startswith(("admits:", "denials:", "spills:",
                                "unroutable:")):
-                assert gw_f.store.get(key) == gw_g.store.get(key), key
+                assert gw_q.store.get(key) == gw_s.store.get(key), key
 
-    def test_multi_leg_routes_bail_to_generic(self):
-        """A single multi-leg key must disable the fast path for the
-        whole quantum — and leave no partial state behind."""
-        mgr = PoolManager([mkpool("a", tps=10.0), mkpool("b")])
-        mgr.pool("a").add_entitlement(ent("e@a", "a", tps=10.0))
-        mgr.pool("b").add_entitlement(ent("e@b", "b"))
-        gw = Gateway(mgr)
-        gw.register_route("k", [("a", "e@a"), ("b", "e@b")])
-        assert gw._quantum_fast(
-            [QuantumRequest("k", "r1", 32, 64),
-             QuantumRequest("k", "r2", 32, 64)], 0.0) is None
-        # nothing admitted / counted by the aborted fast attempt
-        assert gw.store.keys("admits:") == []
-        assert not mgr.pool("a").in_flight and not mgr.pool("b").in_flight
-        # the full quantum still works end to end (generic path)
-        resps = gw.handle_quantum(
-            [QuantumRequest("k", "r1", 32, 64),
-             QuantumRequest("k", "r2", 32, 64)], now=0.0)
-        assert [r.status for r in resps] == [200, 200]
+    def test_mixed_single_and_multi_leg_quantum_matches_scalar(self):
+        """Single-leg keys on ``a`` and ``c`` and a two-leg key a → b
+        in one quantum: every decision is the scalar loop's, and each
+        pool is dispatched once in each round that reaches it, pools
+        in the order of their earliest request."""
+
+        def build(telemetry):
+            mgr = PoolManager([mkpool("a", tps=200.0), mkpool("b"),
+                               mkpool("c", tps=150.0)])
+            for pname, tps in (("a", 200.0), ("b", 500.0), ("c", 150.0)):
+                mgr.pool(pname).add_entitlement(ent(f"e@{pname}", pname,
+                                                    tps=tps))
+            gw = Gateway(mgr, telemetry=telemetry)
+            gw.register_route("sa", [("a", "e@a")])
+            gw.register_route("sc", [("c", "e@c")])
+            gw.register_route("m", [("a", "e@a"), ("b", "e@b")])
+            return gw
+
+        keys = ["sc", "m", "sa", "m", "sc", "m", "sa"]
+        reqs = [QuantumRequest(k, f"r{i}", 32, 64)
+                for i, k in enumerate(keys)]
+        gw_q, gw_s = build(True), build(False)
+        quantum = gw_q.handle_quantum(reqs, now=0.0)
+        scalar = [gw_s.handle(q.api_key, q.request_id, q.input_tokens,
+                              q.max_tokens, now=0.0) for q in reqs]
+        assert [_resp_key(r) for r in quantum] == \
+            [_resp_key(r) for r in scalar]
+        assert {(r.status, r.pool) for r in quantum} >= {
+            (200, "a"), (200, "b"), (200, "c"), (429, None)}
+        for rq, rs in zip(quantum, scalar):
+            assert rq.priority == pytest.approx(rs.priority, rel=1e-5)
+        for key in set(gw_q.store.keys()) | set(gw_s.store.keys()):
+            if key.startswith(("admits:", "denials:", "spills:")):
+                assert gw_q.store.get(key) == gw_s.store.get(key), key
+        r = gw_q.telemetry.spans.rows()
+        rounds = [i for i in range(r["id"].size)
+                  if r["name"][i] == "gateway.round"]
+        dispatched = [[r["pool"][i] for i in range(r["id"].size)
+                       if r["name"][i] == "gateway.admit"
+                       and r["parent"][i] == r["id"][rnd]]
+                      for rnd in rounds]
+        assert dispatched == [["c", "a"], ["b"]]

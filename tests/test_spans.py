@@ -157,19 +157,25 @@ class TestProgramSpans:
 
     def test_generic_path_and_group_tick_share_the_names(self):
         gw = mkgateway(pools=("a", "b"))
-        gw.quantum_fast_enabled = False
+        # k2's home entitlement is not bound on a, so it spills to b:
+        # the quantum runs in leg rounds
+        gw.register_route("k2@a", [("a", "e2@a"), ("b", "e0@b")])
         quantum(gw, 8, "x", 0.0, pool="a")
         gw.manager.tick(1.0)                  # one group of two pools
         r = gw.telemetry.spans.rows()
         q = int(r["id"][only(r, "gateway.quantum")])
-        # round 0 through the kernel, then the 429s of exhausted routes
+        # round 0 on a answers k3's exhausted route in its own deny
+        # span; round 1 admits k2's spill on b
         assert children(r, q) == [
-            "gateway.route", "gateway.round", "gateway.deny"]
-        rnd = only(r, "gateway.round")
-        assert r["index"][rnd] == 0
-        assert children(r, int(r["id"][rnd])) == [
+            "gateway.route", "gateway.round", "gateway.round"]
+        rnd = np.flatnonzero(r["name"] == "gateway.round")
+        assert list(r["index"][rnd]) == [0, 1]
+        assert children(r, int(r["id"][rnd[0]])) == [
             "gateway.snapshot", "gateway.admit", "gateway.charge",
             "gateway.deny", "gateway.record"]
+        assert children(r, int(r["id"][rnd[1]])) == [
+            "gateway.snapshot", "gateway.admit", "gateway.charge",
+            "gateway.record"]
         tick = only(r, "pool.tick")
         assert r["pool"][tick] == ""
         assert children(r, int(r["id"][tick])) == [

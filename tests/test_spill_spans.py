@@ -1,6 +1,6 @@
 """Spans and counters of spill routing, on a two-pool fleet whose keys
 each have a home leg on pool ``a`` and a spill leg on pool ``b``:
-``gateway.round`` per leg round of the generic quantum, ``gateway.settle``
+``gateway.round`` per leg round of a quantum, ``gateway.settle``
 per batch of completions with a ``pool.spill_debt`` child where it
 completes a spill-served request, and ``repro_spill_admits_total`` /
 ``repro_spill_debt_moved_total``."""
@@ -138,6 +138,26 @@ def test_spill_admits_count_the_200s_with_spill_hops():
     assert spilled == 4
     assert _counter(gw.telemetry, "repro_spill_admits_total") == {
         ("a", "b"): 4.0}
+
+
+def test_spill_admits_count_a_single_live_leg_past_a_down_home():
+    """With ``a`` down, ``cold`` has one live leg, on ``b``, at declared
+    position 1: its admits are spill admits, in a quantum as in the
+    scalar ``handle``."""
+    counts = {}
+    for batched in (True, False):
+        gw, pools = spill_fleet()
+        pools["a"].set_replicas(0)
+        if batched:
+            resps = quantum(gw, ["cold", "cold"], "q", 0.0)
+        else:
+            resps = [gw.handle("cold", f"q{i}", 64, 64, 0.0)
+                     for i in range(2)]
+        assert [(r.status, r.pool, r.spill_hops) for r in resps] == [
+            (200, "b", 1)] * 2
+        assert gw.store.get("spills:cold") == 2.0
+        counts[batched] = _counter(gw.telemetry, "repro_spill_admits_total")
+    assert counts[True] == counts[False] == {("b", "b"): 2.0}
 
 
 def test_spill_debt_moved_sums_what_each_transfer_returned(monkeypatch):
